@@ -22,14 +22,14 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_smoke_config
 from repro.dist.sharding import materialize_params
-from repro.launch.mesh import rules_for
+from repro.launch.mesh import make_mesh, rules_for
 from repro.models.api import build_model, synth_batch
 from repro.models.layers import ModelContext
 from repro.optim.grad_compress import tree_compressed_pmean
 
 
 def main() -> int:
-    mesh = jax.make_mesh((4, 1), ("data", "model"))
+    mesh = make_mesh((4, 1), ("data", "model"))
     cfg = get_smoke_config("smollm-135m")
     rules = rules_for(mesh)
     with mesh:

@@ -6,13 +6,5 @@
 
 Each has a pure-jnp oracle in ref.py and a jit'd dispatch wrapper in ops.py
 (pallas on TPU, interpret=True for CPU validation, jnp fallback).
-
-Kernels are written against the current Pallas API spelling
-(``pltpu.CompilerParams``); _compat aliases the old name before any kernel
-module loads.
 """
-from repro._compat.jaxshims import ensure_pallas_compat
-
-ensure_pallas_compat()
-
-from repro.kernels import ops, ref  # noqa: E402
+from repro.kernels import ops, ref  # noqa: F401

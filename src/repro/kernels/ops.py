@@ -86,8 +86,10 @@ def paged_attention(
         .reshape(B, Hkv, T * g, D)
     )
     bt = jnp.clip(block_tables.astype(jnp.int32), 0, P - 1)  # DMA-safe padding
+    # the kernel reads head-major pages (its module docstring says why)
     obh = paged_attention_grouped(
-        qg, k_pages, v_pages, bt, lens.astype(jnp.int32),
+        qg, k_pages.transpose(0, 2, 1, 3), v_pages.transpose(0, 2, 1, 3),
+        bt, lens.astype(jnp.int32),
         num_queries=T,
         interpret=(impl == "interpret"),
     )

@@ -3,11 +3,12 @@
 The serving engine stores KV in a page pool ``(P, page_size, Hkv, D)``; a
 sequence's cache is the ordered list of physical pages its ``PageTable``
 block table names.  This kernel attends a small block of freshly written
-query tokens per sequence directly against that pool: the block table is a
-**scalar-prefetched** operand, so each grid step's BlockSpec index_map reads
-``bt[b, i]`` and the page gather *is* the DMA schedule — no dense
-``(B, max_len, ...)`` cache is ever materialized, and sequences pay for the
-pages they occupy, not for ``max_len``.
+query tokens per sequence against a head-major view of that pool
+(``ops.paged_attention`` makes it; the end of this docstring says why):
+the block table is a **scalar-prefetched** operand, so each grid step's
+BlockSpec index_map reads ``bt[b, i]`` and the page gather *is* the DMA
+schedule — no dense ``(B, max_len, ...)`` cache is ever materialized, and
+sequences pay for the pages they occupy, not for ``max_len``.
 
 The query block covers speculative decode's verify pass: T = k+1 positions
 per sequence attend in ONE kernel launch.  Queries stack into the row axis
@@ -16,15 +17,22 @@ as ``(T*G, D)`` — row ``r`` is query ``t = r // G``, head-group lane
 and compiles to exactly the previous kernel.
 
 Layout: q ``(B, Hkv, T*G, D)`` (T tokens per sequence, q heads grouped by
-their kv head, queries-major), k/v pages ``(P, page_size, Hkv, D)``, block
-tables ``(B, n)`` int32, lens ``(B,)`` int32 — ``lens[b]`` counts valid
-tokens through the FIRST query's own position, so query ``t`` attends
-``pos < lens[b] + t``.  Grid ``(B, Hkv, n)``: the page axis is sequential,
-so the online-softmax stats (m, l, acc) live in VMEM scratch that persists
-across pages — same accumulator discipline as flash_attention.  Pages at or
-beyond every query's reach are skipped with ``pl.when`` (their DMA still
-lands on a valid page — callers pad short block-table rows with any
-in-range page id).
+their kv head, queries-major), k/v pages head-major
+``(P, Hkv, page_size, D)``, block tables ``(B, n)`` int32, lens ``(B,)``
+int32 — ``lens[b]`` counts valid tokens through the FIRST query's own
+position, so query ``t`` attends ``pos < lens[b] + t``.  Grid
+``(B, Hkv, n)``: the page axis is sequential, so the online-softmax stats
+(m, l, acc) live in VMEM scratch that persists across pages — same
+accumulator discipline as flash_attention.  Pages at or beyond every
+query's reach are skipped with ``pl.when`` (their DMA still lands on a
+valid page — callers pad short block-table rows with any in-range page
+id).
+
+The pages are head-major so that one grid step's K/V block is
+``(1, 1, page_size, D)``: its last two dims are whole array dims, which
+Mosaic accepts.  A ``(1, page_size, 1, D)`` block over the token-major
+``(P, page_size, Hkv, D)`` pool puts a one-head slice in the second-minor
+dim, and the TPU compiler refuses it.
 """
 from __future__ import annotations
 
@@ -43,8 +51,8 @@ def _paged_kernel(
     bt_ref,  # (B, n) int32 scalar-prefetch: the block tables
     lens_ref,  # (B,) int32 scalar-prefetch: valid tokens per sequence
     q_ref,  # (1, 1, T*G, D)
-    k_ref,  # (1, page_size, 1, D)
-    v_ref,  # (1, page_size, 1, Dv)
+    k_ref,  # (1, 1, page_size, D)
+    v_ref,  # (1, 1, page_size, Dv)
     o_ref,  # (1, 1, T*G, Dv)
     m_scr,  # (T*G, 1) f32
     l_scr,  # (T*G, 1) f32
@@ -71,8 +79,8 @@ def _paged_kernel(
     @pl.when(i * page_size < seq_len + num_queries - 1)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (T*G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page_size, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (page_size, Dv)
+        k = k_ref[0, 0].astype(jnp.float32)  # (page_size, D)
+        v = v_ref[0, 0].astype(jnp.float32)  # (page_size, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (T*G, page_size)
@@ -98,8 +106,8 @@ def _paged_kernel(
 
 def paged_attention_grouped(
     q: jax.Array,  # (B, Hkv, T*G, D) — queries-major row stacking
-    k_pages: jax.Array,  # (P, page_size, Hkv, D)
-    v_pages: jax.Array,  # (P, page_size, Hkv, Dv)
+    k_pages: jax.Array,  # (P, Hkv, page_size, D) — head-major
+    v_pages: jax.Array,  # (P, Hkv, page_size, Dv)
     block_tables: jax.Array,  # (B, n) int32 physical page ids, in token order
     lens: jax.Array,  # (B,) int32 — valid tokens through the first query
     *,
@@ -108,7 +116,7 @@ def paged_attention_grouped(
     interpret: bool = False,
 ) -> jax.Array:
     B, Hkv, QG, D = q.shape
-    P, page_size, _, Dv = v_pages.shape
+    P, _, page_size, Dv = v_pages.shape
     n = block_tables.shape[1]
     if QG % num_queries:
         raise ValueError(f"query rows {QG} not divisible by T={num_queries}")
@@ -128,10 +136,10 @@ def paged_attention_grouped(
         in_specs=[
             pl.BlockSpec((1, 1, QG, D), lambda b, h, i, bt, ln: (b, h, 0, 0)),
             pl.BlockSpec(
-                (1, page_size, 1, D), lambda b, h, i, bt, ln: (bt[b, i], 0, h, 0)
+                (1, 1, page_size, D), lambda b, h, i, bt, ln: (bt[b, i], h, 0, 0)
             ),
             pl.BlockSpec(
-                (1, page_size, 1, Dv), lambda b, h, i, bt, ln: (bt[b, i], 0, h, 0)
+                (1, 1, page_size, Dv), lambda b, h, i, bt, ln: (bt[b, i], h, 0, 0)
             ),
         ],
         out_specs=pl.BlockSpec((1, 1, QG, Dv), lambda b, h, i, bt, ln: (b, h, 0, 0)),

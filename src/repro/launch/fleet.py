@@ -23,11 +23,20 @@ Subcommands::
     python -m repro.launch.fleet engine --name e0 --addr H:P --dir LOG \\
         --prefix fleet-x --toy ...        # one fleet engine (subprocess)
     python -m repro.launch.fleet demo --engines 2 --requests 8   # local demo
+
+On a host with several TPU chips, ``Fleet(..., pin_chips=True)`` gives
+engine ``i`` chip ``i`` alone through libtpu's per-process visibility
+settings (``TPU_VISIBLE_CHIPS`` with one-chip process bounds), so N engines
+are N one-chip replicas.  Each engine reports the device it holds in its
+READY line (``EngineProc.device``); the process that holds the ``Fleet``
+never initializes a JAX backend, so it holds no chip.
 """
 from __future__ import annotations
 
 import argparse
+import json
 import os
+import socket
 import subprocess
 import sys
 import tempfile
@@ -46,6 +55,27 @@ from repro.core.streaming import (
 
 READY_LINE = "FLEET ENGINE READY"
 LEASE_PREFIX = "fleet"
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("localhost", 0))
+        return s.getsockname()[1]
+
+
+def pin_to_chip(env: dict, chip: int) -> dict:
+    """Make ``chip`` the only TPU chip a process started with ``env`` sees:
+    a one-chip process slice, which libtpu lets load beside other such
+    processes on the same host."""
+    port = _free_port()
+    env.update(
+        TPU_VISIBLE_CHIPS=str(chip),
+        TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+        TPU_PROCESS_BOUNDS="1,1,1",
+        TPU_PROCESS_PORT=str(port),
+        TPU_PROCESS_ADDRESSES=f"localhost:{port}",
+    )
+    return env
 
 
 def _env_with_src() -> dict:
@@ -72,22 +102,27 @@ def _env_with_src() -> dict:
 def _engine_main(args) -> int:
     """One fleet engine: lease heartbeat + serve loop over the fleet topics.
 
-    Prints ``FLEET ENGINE READY <name>`` (flushed) once the lease is held
-    and the initial load cell is published, so the spawner can scrape it.
+    Prints ``FLEET ENGINE READY <name> <device json>`` (flushed) once the
+    lease is held and the initial load cell is published, so the spawner
+    can scrape it and learn which device the engine holds.
     """
-    from repro.configs import get_smoke_config
+    import jax
+
+    from repro.configs import get_config, get_smoke_config
     from repro.dist.lease import LeaseLost, LeaseService
+    from repro.launch.compile_cache import use_compile_cache
     from repro.serve.engine import ServeEngine, serve_context
 
-    cfg = get_smoke_config(args.arch)
-    ctx = serve_context(cfg)
+    use_compile_cache()
+    cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
+    ctx = serve_context(
+        cfg, use_kernels=args.use_kernels, page_size=args.page_size
+    )
     if args.toy:
         from repro.serve.toy import CountingModel
 
         model, params = CountingModel(cfg), {}
     else:
-        import jax
-
         from repro.dist.sharding import materialize_params
         from repro.models.api import build_model
 
@@ -96,6 +131,14 @@ def _engine_main(args) -> int:
             params = materialize_params(
                 model.param_specs(), jax.random.PRNGKey(0)
             )
+    dev = jax.devices()[0]
+    device = {
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "count": len(jax.devices()),
+        "id": dev.id,
+        "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+    }
 
     name = args.name
     ctl_store = Store(
@@ -127,7 +170,7 @@ def _engine_main(args) -> int:
     lease = LeaseService(ctl_store, ttl=args.ttl, prefix=LEASE_PREFIX)
     gen = [lease.register(name)]
     ctl_store.put(engine.pages.pages_available(), key=f"load-{name}")
-    print(f"{READY_LINE} {name}", flush=True)
+    print(f"{READY_LINE} {name} {json.dumps(device)}", flush=True)
 
     stop = threading.Event()
     beat_errors = [0]
@@ -187,13 +230,17 @@ class EngineProc:
         *,
         arch: str = "smollm-135m",
         toy: bool = True,
+        smoke: bool = True,
+        use_kernels: bool = False,
         slots: int = 2,
         max_len: int = 32,
         page_size: int = 4,
         ttl: float = 3.0,
         hold_key: str | None = None,
+        chip: int | None = None,
     ):
         self.name = name
+        self.device: dict | None = None  # from the READY line
         cmd = [
             sys.executable, "-m", "repro.launch.fleet", "engine",
             "--name", name, "--addr", addr, "--dir", logdir,
@@ -201,15 +248,20 @@ class EngineProc:
             "--slots", str(slots), "--max-len", str(max_len),
             "--page-size", str(page_size), "--ttl", str(ttl),
         ]
-        if toy:
-            cmd.append("--toy")
+        for flag, on in (("--toy", toy), ("--smoke", smoke),
+                         ("--use-kernels", use_kernels)):
+            if on:
+                cmd.append(flag)
         if hold_key:
             cmd += ["--hold-key", hold_key]
+        env = _env_with_src()
+        if chip is not None:
+            pin_to_chip(env, chip)
         self._errpath = os.path.join(logdir, f"{name}.stderr")
         self._errfile = open(self._errpath, "wb")
         self.proc = subprocess.Popen(
             cmd,
-            env=_env_with_src(),
+            env=env,
             stdout=subprocess.PIPE,
             stderr=self._errfile,
         )
@@ -229,7 +281,9 @@ class EngineProc:
                     f"fleet engine {self.name} exited before READY "
                     f"(rc={self.proc.poll()}):\n{err}"
                 )
-            if line.decode(errors="replace").startswith(READY_LINE):
+            text = line.decode(errors="replace")
+            if text.startswith(READY_LINE):
+                self.device = json.loads(text[len(READY_LINE):].split(None, 1)[1])
                 break
         # drain further stdout so the pipe can never fill and block the
         # engine's prints
@@ -275,6 +329,9 @@ class Fleet:
         *,
         arch: str = "smollm-135m",
         toy: bool = True,
+        smoke: bool = True,
+        use_kernels: bool = False,
+        pin_chips: bool = False,
         slots: int = 2,
         max_len: int = 32,
         page_size: int = 4,
@@ -285,12 +342,12 @@ class Fleet:
         consumer_timeout: float = 300.0,
         on_done=None,
     ):
-        from repro.configs import get_smoke_config
+        from repro.configs import get_config, get_smoke_config
         from repro.dist.lease import LeaseService
         from repro.serve.client import ServeClient
         from repro.serve.router import Router
 
-        self.cfg = get_smoke_config(arch)
+        self.cfg = (get_smoke_config if smoke else get_config)(arch)
         self.names = [f"e{i}" for i in range(n_engines)]
         self.logdir = logdir or tempfile.mkdtemp(prefix="fleet-log-")
         self.prefix = f"fleet-{new_key()}"
@@ -309,11 +366,12 @@ class Fleet:
         self.procs = {
             name: EngineProc(
                 name, addr, self.logdir, self.prefix,
-                arch=arch, toy=toy, slots=slots, max_len=max_len,
-                page_size=page_size, ttl=ttl,
+                arch=arch, toy=toy, smoke=smoke, use_kernels=use_kernels,
+                slots=slots, max_len=max_len, page_size=page_size, ttl=ttl,
                 hold_key=f"hold-{name}" if name in hold else None,
+                chip=i if pin_chips else None,
             )
-            for name in self.names
+            for i, name in enumerate(self.names)
         }
         for proc in self.procs.values():
             proc.wait_ready()
@@ -472,6 +530,11 @@ def main(argv=None) -> int:
     eng.add_argument("--arch", default="smollm-135m")
     eng.add_argument("--toy", action="store_true",
                      help="CountingModel instead of the real arch")
+    eng.add_argument("--smoke", action="store_true",
+                     help="the reduced same-family config instead of the "
+                          "published widths")
+    eng.add_argument("--use-kernels", action="store_true",
+                     help="attention through the Pallas kernel ops")
     eng.add_argument("--slots", type=int, default=2)
     eng.add_argument("--max-len", type=int, default=32)
     eng.add_argument("--page-size", type=int, default=4)

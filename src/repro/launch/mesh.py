@@ -21,11 +21,21 @@ import threading
 import time
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.dist.fault import MeshPlan, elastic_plan
 from repro.dist.lease import LeaseService, MembershipSnapshot
 from repro.dist.sharding import AxisRules, DEFAULT_RULES, MULTIPOD_RULES, RULE_PROFILES
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the code shards through
+    ``with_sharding_constraint`` and placed inputs, and lets XLA propagate
+    the rest (``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    the engine's page-pool scatters are rejected)."""
+    return jax.make_mesh(
+        shape, axes, devices=devices, axis_types=(AxisType.Auto,) * len(shape)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -33,7 +43,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     devices = jax.devices()[: math.prod(shape)]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def rules_for(mesh: Mesh, profile: str = "default") -> AxisRules:
@@ -43,7 +53,7 @@ def rules_for(mesh: Mesh, profile: str = "default") -> AxisRules:
 
 def make_host_mesh() -> Mesh:
     """1-device mesh for smoke tests / CPU examples (same axis names)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def plan_to_mesh(plan: MeshPlan, *, devices=None) -> Mesh:
@@ -60,7 +70,7 @@ def plan_to_mesh(plan: MeshPlan, *, devices=None) -> Mesh:
         raise ValueError(
             f"plan {plan} needs {need} devices; runtime has {len(devices)}"
         )
-    return jax.make_mesh(shape, names, devices=devices[:need])
+    return make_mesh(shape, names, devices=devices[:need])
 
 
 class ElasticMeshDriver:

@@ -1,6 +1,7 @@
 """Serving driver: continuous-batching engine fed by a ProxyStream.
 
-Runs the reduced (smoke) config of any assigned arch on CPU under the
+Runs any assigned arch at its published widths (``--smoke`` selects the
+reduced same-family config, the one a CPU serves in seconds) under the
 ``serve`` rules profile: a client thread publishes prompt requests
 (metadata → broker, bulk prompt → store) under a backpressure window, the
 engine admits them into slots, decodes greedily, and streams *token deltas*
@@ -11,7 +12,7 @@ The client's send window is bounded by completions (in-flight ≤ 2×slots)
 and every blocking edge has a deadline, so a wedged engine or a full store
 surfaces as a loud error instead of a silently deadlocked driver.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m --smoke \
         --requests 8 --slots 4 --max-new 12
 """
 from __future__ import annotations
@@ -24,7 +25,7 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import arch_names, get_smoke_config
+from repro.configs import arch_names, get_config, get_smoke_config
 from repro.core.store import Store
 from repro.core.streaming import (
     QueuePublisher,
@@ -33,6 +34,7 @@ from repro.core.streaming import (
     StreamProducer,
 )
 from repro.dist.sharding import materialize_params, sharding_tree
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import build_model
 from repro.serve.client import ServeClient
 from repro.serve.engine import ServeEngine, serve_context
@@ -41,6 +43,9 @@ from repro.serve.engine import ServeEngine, serve_context
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="smollm-135m", choices=arch_names(True))
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced same-family config (CPU-servable) "
+                         "instead of the published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
@@ -62,13 +67,17 @@ def main(argv=None) -> int:
                          "slot per step (0 = off); emitted tokens stay "
                          "bit-identical to plain greedy decode")
     ap.add_argument("--draft-config", default=None, choices=arch_names(True),
-                    help="smoke config for the draft model (--spec-k > 0); "
+                    help="config for the draft model (--spec-k > 0); "
                          "defaults to --arch (self-draft)")
     args = ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch)
+    use_compile_cache()
+    pick_config = get_smoke_config if args.smoke else get_config
+    cfg = pick_config(args.arch)
     # serve rules profile: kv_seq over model axis
-    ctx = serve_context(cfg, use_kernels=args.use_kernels)
+    ctx = serve_context(
+        cfg, use_kernels=args.use_kernels, page_size=args.page_size
+    )
     model = build_model(ctx)
     with ctx.mesh:
         params = materialize_params(model.param_specs(), jax.random.PRNGKey(0))
@@ -139,8 +148,10 @@ def main(argv=None) -> int:
             # smaller --draft-config)
             draft_model, draft_params = model, params
         else:
-            dcfg = get_smoke_config(args.draft_config)
-            dctx = serve_context(dcfg, use_kernels=args.use_kernels)
+            dcfg = pick_config(args.draft_config)
+            dctx = serve_context(
+                dcfg, use_kernels=args.use_kernels, page_size=args.page_size
+            )
             draft_model = build_model(dctx)
             with dctx.mesh:
                 draft_params = materialize_params(
@@ -174,7 +185,8 @@ def main(argv=None) -> int:
         )
         spec_note = f" accepted/slot-step {rate:.2f} (spec_k={args.spec_k});"
     print(
-        f"[serve] {args.arch} (smoke): {len(completed)}/{args.requests} requests, "
+        f"[serve] {args.arch}{' (smoke)' if args.smoke else ''}: "
+        f"{len(completed)}/{args.requests} requests, "
         f"{engine.metrics['tokens']} tokens in {wall:.1f}s "
         f"({engine.metrics['tokens']/wall:.1f} tok/s); "
         f"mean latency {np.mean(lat):.2f}s; "

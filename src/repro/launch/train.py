@@ -24,6 +24,7 @@ from repro.data.pipeline import (
     StreamingDataLoader,
     SyntheticCorpus,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, rules_for
 from repro.models.layers import ModelContext
 from repro.optim.adamw import AdamWConfig
@@ -49,6 +50,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None, help="write history JSON here")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh()
     ctx = ModelContext(cfg, mesh, rules_for(mesh))

@@ -31,6 +31,7 @@ class ModelContext:
     mesh: Mesh
     rules: AxisRules
     use_kernels: bool = False  # Pallas path (TPU); jnp blockwise otherwise
+    page_size: int = 16  # KV page the decode kernel reads (engine's pool)
 
     @property
     def batch_axes(self) -> tuple[str, ...]:
@@ -329,32 +330,48 @@ def paged_decode_attention(
 
 def _decode_attention_core(ctx: "ModelContext", q, k_cache, v_cache, length):
     """Decode-step dispatch: when kernels are enabled, view the dense
-    per-slot cache as contiguous pages (an arange block table) and run the
-    paged-attention kernel; else the plain masked jnp decode attention.
-    Handles T ≥ 1 query tokens (q ``(B, T, H, D)``): both backends mask
-    query ``t`` to keys ``< length + t``."""
+    per-slot cache as contiguous ``ctx.page_size`` pages (an arange block
+    table) and run the paged-attention kernel; else the plain masked jnp
+    decode attention.  A shape the kernel cannot take raises rather than
+    quietly running jnp.  Handles T ≥ 1 query tokens (q ``(B, T, H, D)``):
+    both backends mask query ``t`` to keys ``< length + t``."""
     B, S, Hkv, Dv = v_cache.shape
-    if ctx.use_kernels and q.shape[-1] == Dv and S % 16 == 0:
-        from repro.kernels.ops import paged_attention
+    if not ctx.use_kernels:
+        return decode_attention(q, k_cache, v_cache, length)
+    ps = ctx.page_size
+    if q.shape[-1] != Dv or S % ps:
+        raise ValueError(
+            f"paged decode kernel cannot take q head dim {q.shape[-1]} / v "
+            f"head dim {Dv} over a {S}-token cache in {ps}-token pages"
+        )
+    from repro.kernels.ops import paged_attention
 
-        ps = 16
-        n = S // ps
-        kp = k_cache.reshape(B * n, ps, Hkv, k_cache.shape[-1])
-        vp = v_cache.reshape(B * n, ps, Hkv, Dv)
-        bt = jnp.arange(B * n, dtype=jnp.int32).reshape(B, n)
-        lens = jnp.full((B,), length, jnp.int32)
-        return paged_attention(q, kp, vp, bt, lens)
-    return decode_attention(q, k_cache, v_cache, length)
+    n = S // ps
+    kp = k_cache.reshape(B * n, ps, Hkv, k_cache.shape[-1])
+    vp = v_cache.reshape(B * n, ps, Hkv, Dv)
+    bt = jnp.arange(B * n, dtype=jnp.int32).reshape(B, n)
+    lens = jnp.full((B,), length, jnp.int32)
+    return paged_attention(q, kp, vp, bt, lens)
 
 
 def _attention_core(ctx: "ModelContext", q, k, v, *, causal: bool,
                     scale: float | None = None):
     """Dispatch: Pallas flash-attention kernel (TPU / interpret) when
-    ``ctx.use_kernels`` and shapes allow (uniform head dim, no custom
-    scale), else the pure-jnp blockwise path."""
+    ``ctx.use_kernels``, else the pure-jnp blockwise path.  The kernel
+    takes a uniform head dim, the default scale and scanned layers; any
+    other shape raises rather than quietly running jnp."""
     cfg = ctx.cfg
-    if (ctx.use_kernels and scale is None
-            and q.shape[-1] == v.shape[-1] and cfg.scan_layers):
+    if ctx.use_kernels:
+        Sq, Sk = q.shape[1], k.shape[1]
+        if (scale is not None or q.shape[-1] != v.shape[-1]
+                or not cfg.scan_layers
+                or (Sq > 128 and Sq % 128) or (Sk > 128 and Sk % 128)):
+            raise ValueError(
+                f"flash-attention kernel cannot take q {q.shape} / v "
+                f"{v.shape} (scale={scale}, scan_layers={cfg.scan_layers}): "
+                "it needs a uniform head dim, the default scale, scanned "
+                "layers and sequences of at most 128 or a multiple of 128"
+            )
         from repro.kernels.ops import flash_attention
 
         return flash_attention(q, k, v, causal=causal)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat.jaxshims import shard_map
 from repro.configs.base import ArchConfig
 from repro.dist.sharding import ParamSpec
 from repro.models.layers import ModelContext
@@ -135,7 +134,7 @@ def apply_moe(ctx: ModelContext, params: dict, x: jax.Array):
         )
     else:
         bspec = P(batch_axes if batch_axes else None, None)
-        f = shard_map(
+        f = jax.shard_map(
             partial(_local_moe, cfg, model_axis, batch_axes),
             mesh=mesh,
             in_specs=(

@@ -13,8 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-import repro._compat.jaxshims  # noqa: F401 — installs jax.shard_map on 0.4.x
-
 
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Per-tensor symmetric int8: returns (q, scale)."""

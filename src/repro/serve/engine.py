@@ -64,7 +64,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 import jax
 import jax.numpy as jnp
@@ -86,18 +86,23 @@ from repro.models.layers import ModelContext
 _WAIT_TICK = 0.25
 
 
-def serve_context(cfg, mesh=None, *, use_kernels: bool = False) -> ModelContext:
+def serve_context(
+    cfg, mesh=None, *, use_kernels: bool = False, page_size: int = 16
+) -> ModelContext:
     """ModelContext with the ``serve`` rules profile applied.
 
     The serve profile shards the KV cache's sequence axis over the model
     axis (``kv_seq`` wins the model axis; decode is KV-bound) — the rules
     flow into both param placement and the cache shardings the engine
-    applies in :meth:`ServeEngine._ensure_cache`.
+    applies in :meth:`ServeEngine._ensure_cache`.  ``page_size`` is the
+    KV page the decode kernel reads (``ServeEngine`` sets it to its own).
     """
     from repro.launch.mesh import make_host_mesh, rules_for
 
     mesh = mesh if mesh is not None else make_host_mesh()
-    return ModelContext(cfg, mesh, rules_for(mesh, "serve"), use_kernels)
+    return ModelContext(
+        cfg, mesh, rules_for(mesh, "serve"), use_kernels, page_size
+    )
 
 
 @dataclass
@@ -141,6 +146,8 @@ class ServeEngine:
         from repro.core.connectors import new_key
         from repro.serve.kvcache import PageTable
 
+        # the decode kernel reads the KV cache in the pool's own pages
+        ctx = replace(ctx, page_size=page_size)
         self.ctx = ctx
         self.cfg = ctx.cfg
         self.model = model if model is not None else build_model(ctx)

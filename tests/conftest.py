@@ -1,9 +1,9 @@
-"""Suite bootstrap: src/ on sys.path, hypothesis fallback, multiproc guard,
-and the ProxySan plugin.
+"""Suite bootstrap: src/ on sys.path, multiproc guard, and the ProxySan
+plugin.
 
 The sys.path insert duplicates pyproject's ``pythonpath`` on purpose: this
-conftest imports ``repro`` itself (for the hypothesis stub) and must not
-depend on ini-option processing order.
+conftest imports ``repro`` itself (for the sanitizer) and must not depend on
+ini-option processing order.
 
 ``@pytest.mark.multiproc`` tests spawn subprocesses (lease workers, chaos
 victims) and could wedge the tier-1 gate if a child never writes the key
@@ -33,12 +33,7 @@ _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from repro._compat import hypothesis_stub
-
-    hypothesis_stub.install()
+import hypothesis  # noqa: E402,F401
 
 
 def pytest_configure(config):

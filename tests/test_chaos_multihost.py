@@ -30,7 +30,7 @@ from repro.core import FileConnector, Store
 from repro.data.pipeline import SyntheticCorpus
 from repro.dist.fault import MeshPlan
 from repro.dist.lease import LeaseService
-from repro.launch.mesh import ElasticMeshDriver, rules_for
+from repro.launch.mesh import ElasticMeshDriver, make_mesh, rules_for
 from repro.models.layers import ModelContext
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -78,8 +78,8 @@ def _smoke_mesh(plan: MeshPlan):
     """Map any MeshPlan onto this box's 1 device, keeping the plan's axis
     character so rules_for still switches pod/multipod resolution."""
     if plan.pods > 1:
-        return jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
-    return jax.make_mesh((1, 1), ("data", "model"))
+        return make_mesh((1, 1, 1), ("pod", "data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 TTL = 2.0  # generous: a CPU-share-throttled box must not flap healthy leases

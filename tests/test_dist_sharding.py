@@ -20,7 +20,7 @@ from repro.dist.sharding import (
     logical_to_spec,
     materialize_params,
 )
-from repro.launch.mesh import make_host_mesh, rules_for
+from repro.launch.mesh import make_host_mesh, make_mesh, rules_for
 from repro.models.api import build_model
 from repro.models.layers import ModelContext
 
@@ -106,8 +106,8 @@ class TestShardConstraint:
             sh._noop_constraint_warned = old
 
     def test_multi_device_places_real_constraint(self):
-        """Dry-run under a forced 4-device mesh: the lowered HLO carries a
-        Sharding custom-call and the constrained output lands sharded over
+        """Dry-run under a forced 4-device mesh: the lowered program carries
+        a sharding constraint and the constrained output lands sharded over
         the data axis (subprocess — the main process must keep 1 device)."""
         import os
         import subprocess
@@ -119,15 +119,16 @@ class TestShardConstraint:
             os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
             import jax, jax.numpy as jnp, numpy as np
             from repro.dist.sharding import DEFAULT_RULES, shard_constraint
+            from repro.launch.mesh import make_mesh
 
-            mesh = jax.make_mesh((4, 1), ("data", "model"))
+            mesh = make_mesh((4, 1), ("data", "model"))
 
             def f(x):
                 return shard_constraint(x, ("batch", None), DEFAULT_RULES, mesh)
 
             x = jnp.zeros((8, 4), jnp.float32)
             txt = jax.jit(f).lower(x).as_text()
-            assert "Sharding" in txt, txt  # constraint reached the HLO
+            assert "sdy.sharding_constraint" in txt, txt  # reached the HLO
             out = jax.jit(f)(x)
             shards = {s.device.id: s.index for s in out.addressable_shards}
             assert len(shards) == 4  # one shard per device over batch
@@ -188,7 +189,7 @@ class TestMaterializeDeterminism:
         ).param_specs()
         with make_host_mesh():
             a = materialize_params(specs, jax.random.PRNGKey(0))
-        with jax.make_mesh((1,), ("model",)):
+        with make_mesh((1,), ("model",)):
             b = materialize_params(specs, jax.random.PRNGKey(0))
         jax.tree.map(
             lambda x, y: np.testing.assert_array_equal(np.asarray(x), np.asarray(y)),
@@ -213,9 +214,9 @@ class TestMaterializeDeterminism:
             np.asarray, materialize_params(specs, jax.random.PRNGKey(3))
         )
         meshes = [
-            jax.make_mesh((1, 1), ("data", "model")),
-            jax.make_mesh((1,), ("model",)),
-            jax.make_mesh((1, 1, 1), ("pod", "data", "model")),
+            make_mesh((1, 1), ("data", "model")),
+            make_mesh((1,), ("model",)),
+            make_mesh((1, 1, 1), ("pod", "data", "model")),
         ]
         for profile in RULE_PROFILES:
             for mesh in meshes:

@@ -44,9 +44,10 @@ _SUBPROCESS_BODY = textwrap.dedent("""
     import functools
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.optim.grad_compress import compressed_psum, tree_compressed_pmean
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
 
     # 1) compressed psum tracks the exact mean within the quant grid
     g = jax.random.normal(jax.random.PRNGKey(1), (4, 32), jnp.float32)

@@ -627,12 +627,12 @@ class TestShardingRules:
     )
     def test_spec_always_valid(self, dim, axis):
         """logical_to_spec never produces an indivisible sharding."""
-        import jax
         from jax.sharding import PartitionSpec
 
         from repro.dist.sharding import DEFAULT_RULES, logical_to_spec
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         spec = logical_to_spec((dim,), (axis,), DEFAULT_RULES, mesh)
         assert isinstance(spec, PartitionSpec)
         for entry, d in zip(spec, (dim,)):

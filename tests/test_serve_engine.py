@@ -13,6 +13,7 @@ import inspect
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -631,12 +632,23 @@ class TestLaunchServe:
     regression: a blocked client must never deadlock the driver, and every
     page must be back in the pool at exit)."""
 
+    @pytest.fixture(autouse=True)
+    def _restore_compile_cache(self):
+        """The launcher points JAX's persistent cache at its directory;
+        give the rest of this test process its own setting back."""
+        from jax.experimental.compilation_cache import compilation_cache as cc
+
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+        cc.reset_cache()
+
     @pytest.mark.multiproc(timeout=240)  # watchdog: a wedged driver fails fast
     def test_launch_serve_smoke_exits_clean(self, capsys):
         from repro.launch import serve as launch_serve
 
         rc = launch_serve.main(
-            ["--requests", "5", "--slots", "2", "--max-new", "4",
+            ["--smoke", "--requests", "5", "--slots", "2", "--max-new", "4",
              "--max-len", "32", "--prompt-len", "6"]
         )
         out = capsys.readouterr().out
@@ -644,6 +656,82 @@ class TestLaunchServe:
         # rc==0 already implies it, but pin the exit-path claims explicitly
         assert "pages in use at exit: 0" in out
         assert "5/5 requests" in out
+        assert "(smoke)" in out
+
+    @pytest.mark.multiproc(timeout=240)
+    def test_launch_serve_smoke_with_kernels(self, capsys):
+        """``--use-kernels`` routes prefill and paged decode through the
+        kernel ops (their jnp branch on CPU) and still serves 5/5."""
+        from repro.launch import serve as launch_serve
+
+        rc = launch_serve.main(
+            ["--smoke", "--use-kernels", "--requests", "5", "--slots", "2",
+             "--max-new", "4", "--max-len", "32", "--prompt-len", "6"]
+        )
+        out = capsys.readouterr().out
+        assert rc == 0, out
+        assert "5/5 requests" in out
+        assert "pages in use at exit: 0" in out
+
+    def test_launcher_defaults_to_published_widths(self, monkeypatch):
+        """Without ``--smoke`` the launcher builds the published config;
+        the run is stopped at model build (CPU time, not correctness)."""
+        from repro.configs import get_config
+        from repro.launch import serve as launch_serve
+
+        seen = []
+
+        class Stop(Exception):
+            pass
+
+        def stop(ctx):
+            seen.append(ctx.cfg)
+            raise Stop
+
+        monkeypatch.setattr(launch_serve, "build_model", stop)
+        with pytest.raises(Stop):
+            launch_serve.main(["--requests", "1"])
+        assert seen == [get_config("smollm-135m")]
+
+
+_CACHE_PROBE = (
+    "import jax; from repro.launch.compile_cache import use_compile_cache; "
+    "print(use_compile_cache(), jax.config.jax_compilation_cache_dir)"
+)
+
+
+def _cache_dir_in_subprocess(env_dir):
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], env=env, capture_output=True,
+        text=True, timeout=120, check=True,
+    ).stdout.split()
+    assert out[0] == out[1]  # the helper's answer is what JAX was told
+    return out[0]
+
+
+class TestCompileCache:
+    def test_env_var_wins(self, tmp_path):
+        assert _cache_dir_in_subprocess(str(tmp_path)) == str(tmp_path)
+
+    def test_fixed_checkout_path_across_processes(self):
+        """Unset, the cache sits at one fixed path inside the checkout: two
+        processes land on the same directory (no pid, time or temp name)."""
+        import os
+
+        first = _cache_dir_in_subprocess(None)
+        assert first == _cache_dir_in_subprocess(None)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(root, ".jax_cache")
 
 
 class TestPagedDecode:

@@ -22,7 +22,7 @@ from repro.data.pipeline import (
 )
 from repro.dist.fault import HeartbeatMonitor, StragglerPolicy, elastic_plan
 from repro.dist.sharding import materialize_params
-from repro.launch.mesh import make_host_mesh, rules_for
+from repro.launch.mesh import make_host_mesh, make_mesh, rules_for
 from repro.models.api import build_model
 from repro.models.layers import ModelContext
 from repro.optim.adamw import AdamWConfig
@@ -89,7 +89,7 @@ class TestCheckpoint:
         mgr = CheckpointManager(str(tmp_path), keep=1)
         mgr.save(params, step=1)
 
-        mesh2 = jax.make_mesh((1,), ("model",))
+        mesh2 = make_mesh((1,), ("model",))
         from repro.dist.sharding import DEFAULT_RULES, sharding_tree
 
         sh = sharding_tree(model.param_specs(), DEFAULT_RULES, mesh2)
@@ -132,7 +132,7 @@ class TestTrainerFaults:
         trainer.init_state()
         trainer.train(data(ctx, 3), 2, log=lambda m: None)
         before = jax.tree.map(np.asarray, trainer.state["params"])
-        new_mesh = jax.make_mesh((1, 1), ("data", "model"))
+        new_mesh = make_mesh((1, 1), ("data", "model"))
         trainer.remesh(ModelContext(ctx.cfg, new_mesh, rules_for(new_mesh)))
         after = jax.tree.map(np.asarray, trainer.state["params"])
         jax.tree.map(np.testing.assert_array_equal, before, after)

@@ -143,3 +143,44 @@ def test_attention_core_kernel_dispatch():
     o_k = _attention_core(ctx_k, q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(o_j), np.asarray(o_k),
                                rtol=2e-3, atol=2e-3)
+
+
+def _kernel_ctx(page_size=16, **cfg_kw):
+    mesh = make_host_mesh()
+    cfg = get_smoke_config("smollm-135m").with_(**cfg_kw)
+    return ModelContext(cfg, mesh, rules_for(mesh), use_kernels=True,
+                        page_size=page_size)
+
+
+def test_decode_attention_core_pages_from_context():
+    """With kernels on, the decode core reads the cache in the caller's
+    page size (here 8 over a 40-token cache) and agrees with jnp; a cache
+    the pages do not tile raises instead of quietly running jnp."""
+    key = jax.random.PRNGKey(3)
+    q = jax.random.normal(key, (2, 1, 4, 32), jnp.float32)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (2, 40, 2, 32), jnp.float32)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (2, 40, 2, 32), jnp.float32)
+    length = jnp.int32(29)
+    want = L.decode_attention(q, k, v, length)
+    got = L._decode_attention_core(_kernel_ctx(page_size=8), q, k, v, length)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="16-token pages"):
+        L._decode_attention_core(_kernel_ctx(page_size=16), q, k, v, length)
+    with pytest.raises(ValueError, match="head dim"):
+        L._decode_attention_core(_kernel_ctx(page_size=8), q, k, v[..., :16],
+                                 length)
+
+
+@pytest.mark.parametrize("case", ["scale", "unrolled", "ragged_seq"])
+def test_attention_core_raises_where_kernel_cannot_run(case):
+    """With kernels on, a shape the flash kernel cannot take raises rather
+    than returning the jnp blockwise result."""
+    key = jax.random.PRNGKey(4)
+    S = 200 if case == "ragged_seq" else 64
+    q = jax.random.normal(key, (1, S, 4, 32), jnp.float32)
+    kv = jax.random.normal(jax.random.fold_in(key, 1), (1, S, 2, 32), jnp.float32)
+    ctx = _kernel_ctx(scan_layers=case != "unrolled")
+    scale = 0.5 if case == "scale" else None
+    with pytest.raises(ValueError, match="flash-attention kernel cannot take"):
+        L._attention_core(ctx, q, kv, kv, causal=True, scale=scale)
