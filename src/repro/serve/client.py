@@ -5,19 +5,26 @@ The response topic carries two event kinds (see ServeEngine.run):
 - ``kind="delta"`` — metadata-only (``StreamProducer.send_meta``): one
   generated token per decode step.  No store payload; the broker event is
   the whole message, so first-token latency is one decode step + one event
-  hop, not a full generation.
+  hop, not a full generation.  ``sent_at``, where present, is the engine's
+  ``time.perf_counter()`` at the send: comparable across processes on one
+  host only.
 - ``kind="done"``  — the completion record (tokens, latency, ttft) as bulk
   via proxy; resolving it is the only store round-trip per request.
 - ``kind="error"`` — admission rejection (metadata-only).
 
 :class:`ServeClient` consumes the topic with ``next_with_metadata`` and
 keeps per-request assembly state; it is the measurement point for the
-streamed-vs-complete latency claims (BENCH_serve's ``ttft_speedup``).
+streamed-vs-complete latency claims (BENCH_serve's ``ttft_speedup``).  It
+marks the handling of each delta with a ``serve.client.delta``
+``jax.profiler.TraceAnnotation`` whose ``hop_us`` stat is the stream hop:
+receipt minus ``sent_at`` (absent where the delta carries no ``sent_at``).
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.proxy import extract
 from repro.core.streaming import StreamConsumer
@@ -81,15 +88,19 @@ class ServeClient:
             return None
         rec = self._rec(req_id)
         if kind == "delta":
-            if rec.first_delta_at is None:
-                rec.first_delta_at = time.perf_counter()
-            if meta["index"] != len(rec.stream_tokens):
-                self.out_of_order.append(
-                    (rec.req_id, meta["index"], len(rec.stream_tokens))
-                )
-            rec.stream_tokens.append(meta["token"])
-            if self.on_delta is not None:
-                self.on_delta(rec.req_id, meta["token"], meta["index"])
+            now = time.perf_counter()
+            sent_at = meta.get("sent_at")
+            hop = {} if sent_at is None else {"hop_us": 1e6 * (now - sent_at)}
+            with TraceAnnotation("serve.client.delta", **hop):
+                if rec.first_delta_at is None:
+                    rec.first_delta_at = now
+                if meta["index"] != len(rec.stream_tokens):
+                    self.out_of_order.append(
+                        (rec.req_id, meta["index"], len(rec.stream_tokens))
+                    )
+                rec.stream_tokens.append(meta["token"])
+                if self.on_delta is not None:
+                    self.on_delta(rec.req_id, meta["token"], meta["index"])
             return None
         if rec.done:  # duplicate error/done for a finished record
             self.rejections.append((req_id, meta.get("error", kind)))

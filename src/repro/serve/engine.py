@@ -42,6 +42,16 @@ admitted only when the pool can cover its *whole* generation, so decode
 never OOMs mid-sequence; requests the pool can never fit are rejected onto
 the response stream as errors.
 
+The engine thread marks each phase of ``run`` with a leaf
+``jax.profiler.TraceAnnotation`` (``serve.admit.*``, ``serve.decode.*``,
+``serve.idle``): no span encloses another, so a profiler trace names every
+idle gap of the device by the phase the host was in.  A span carries its
+parent as a stat instead: ``step``, the ``decode_steps`` counter when it
+opens, and for admission ``batch``, the ``admissions`` counter.  With no
+profiler running a span costs about a microsecond.  Each token delta
+carries ``sent_at``, the engine's ``time.perf_counter()`` at the send: a
+host-monotonic clock, comparable across processes on one host only.
+
 Speculative decode (``spec_k > 0`` + a ``draft_model``): each step, the
 draft proposes up to k tokens per active slot (k+1 chained single-token
 steps over its own page pool, re-feeding the previous token so the draft
@@ -84,6 +94,8 @@ from repro.models.layers import ModelContext
 # condition / connector wait_for) and wake immediately on traffic; the tick
 # only bounds how long shutdown can lag.
 _WAIT_TICK = 0.25
+
+_span = jax.profiler.TraceAnnotation
 
 
 def serve_context(
@@ -295,6 +307,10 @@ class ServeEngine:
             "spec_accepted_tokens": 0,
             "reclaim_failures": 0,
             "load_publish_failures": 0,
+            "admissions": 0,  # admission batches run
+            # sum over admitted requests of their batch's start minus
+            # Request.arrived (the puller's receipt)
+            "queue_wait_s": 0.0,
         }
 
     def _page_bytes(self, page_size: int) -> int:
@@ -629,91 +645,111 @@ class ServeEngine:
                 ids[j] = p
         return ids
 
+    def _admit_span(self, name: str, **stats):
+        """A leaf span of admission: its decode gap and its batch as stats."""
+        return _span(name, step=self.metrics["decode_steps"],
+                     batch=self.metrics["admissions"], **stats)
+
+    def _prefill_batch(self, batch: list[tuple[Request, int]], sp: int):
+        """Enqueue one prefill of every slot's row, padded to ``sp`` tokens,
+        and one donated multi-page insert; returns the prefill logits."""
+        B = len(self.slots)
+        mp = self._pages_per_slot
+        tokens = np.zeros((B, sp), np.int32)
+        lens = np.ones((B,), np.int32)  # pad rows decode garbage, unread
+        ids = np.full((B * mp,), self._null_page, np.int32)
+        for req, slot_idx in batch:
+            tokens[slot_idx, : len(req.prompt)] = req.prompt
+            lens[slot_idx] = len(req.prompt)
+            ids[slot_idx * mp : (slot_idx + 1) * mp] = self._slot_ids_row(
+                req.req_id
+            )
+        logits, caches = self._prefill_many(
+            self.params, jnp.asarray(tokens), jnp.asarray(lens)
+        )
+        self._cache = self._insert_pages(self._cache, caches, jnp.asarray(ids))
+        if self.spec_k:
+            ids_d = np.full((B * mp,), self._null_page, np.int32)
+            for req, slot_idx in batch:
+                ids_d[slot_idx * mp : (slot_idx + 1) * mp] = (
+                    self._slot_ids_row(req.req_id, self.draft_pages)
+                )
+            if hasattr(self.draft_model, "prefill_batch"):
+                _, dcaches = self._draft_prefill_many(
+                    self.draft_params, jnp.asarray(tokens), jnp.asarray(lens)
+                )
+                self._draft_cache = self._insert_pages(
+                    self._draft_cache, dcaches, jnp.asarray(ids_d)
+                )
+            else:
+                for req, slot_idx in batch:
+                    prompt = jnp.asarray(req.prompt[None], jnp.int32)
+                    _, dcache1 = self._draft_prefill(self.draft_params, prompt)
+                    self._draft_cache = self._insert_pages(
+                        self._draft_cache,
+                        dcache1,
+                        jnp.asarray(
+                            self._slot_ids_row(req.req_id, self.draft_pages)
+                        ),
+                    )
+        if len(batch) > 1:
+            self.metrics["batched_prefills"] += 1
+        return logits
+
+    def _prefill_one(self, req: Request, slot_idx: int):
+        """Enqueue one request's prefill and insert; returns its logits."""
+        prompt = jnp.asarray(req.prompt[None], jnp.int32)
+        logits, cache1 = self._prefill(self.params, prompt)
+        if self.paged:
+            ids = self._slot_ids_row(req.req_id)
+            self._cache = self._insert_pages(
+                self._cache, cache1, jnp.asarray(ids)
+            )
+            if self.spec_k:
+                _, dcache1 = self._draft_prefill(self.draft_params, prompt)
+                self._draft_cache = self._insert_pages(
+                    self._draft_cache,
+                    dcache1,
+                    jnp.asarray(
+                        self._slot_ids_row(req.req_id, self.draft_pages)
+                    ),
+                )
+        else:
+            self._cache = self._admit_cache(
+                self._cache, cache1, jnp.int32(slot_idx)
+            )
+        return logits
+
     def _insert_prefill(self, batch: list[tuple[Request, int]]) -> list[int]:
         """Prefill + device insert for admitted requests; returns each
         request's first token (from the prefill logits).  One padded
         prefill and one donated multi-page insert cover the whole batch on
         the paged path; the dense path and non-batching models insert one
         request at a time."""
-        firsts: list[int] = []
-        self._ensure_cache()
-        if self.paged:
-            self._apply_cow()  # allocate-time COW copies land before insert
-        if self.paged and self._can_batch and (
+        batched = self.paged and self._can_batch and (
             self.batch_prefill or len(batch) > 1
-        ):
-            B = len(self.slots)
-            mp = self._pages_per_slot
-            sp = max(len(req.prompt) for req, _ in batch)
-            tokens = np.zeros((B, sp), np.int32)
-            lens = np.ones((B,), np.int32)  # pad rows decode garbage, unread
-            ids = np.full((B * mp,), self._null_page, np.int32)
-            for req, slot_idx in batch:
-                tokens[slot_idx, : len(req.prompt)] = req.prompt
-                lens[slot_idx] = len(req.prompt)
-                ids[slot_idx * mp : (slot_idx + 1) * mp] = self._slot_ids_row(
-                    req.req_id
-                )
-            logits, caches = self._prefill_many(
-                self.params, jnp.asarray(tokens), jnp.asarray(lens)
-            )
-            self._cache = self._insert_pages(self._cache, caches, jnp.asarray(ids))
-            if self.spec_k:
-                ids_d = np.full((B * mp,), self._null_page, np.int32)
-                for req, slot_idx in batch:
-                    ids_d[slot_idx * mp : (slot_idx + 1) * mp] = (
-                        self._slot_ids_row(req.req_id, self.draft_pages)
-                    )
-                if hasattr(self.draft_model, "prefill_batch"):
-                    _, dcaches = self._draft_prefill_many(
-                        self.draft_params, jnp.asarray(tokens), jnp.asarray(lens)
-                    )
-                    self._draft_cache = self._insert_pages(
-                        self._draft_cache, dcaches, jnp.asarray(ids_d)
-                    )
-                else:
-                    for req, slot_idx in batch:
-                        prompt = jnp.asarray(req.prompt[None], jnp.int32)
-                        _, dcache1 = self._draft_prefill(self.draft_params, prompt)
-                        self._draft_cache = self._insert_pages(
-                            self._draft_cache,
-                            dcache1,
-                            jnp.asarray(
-                                self._slot_ids_row(req.req_id, self.draft_pages)
-                            ),
-                        )
-            if len(batch) > 1:
-                self.metrics["batched_prefills"] += 1
-            logits_np = np.asarray(logits, np.float32)
-            firsts = [
-                int(np.argmax(logits_np[slot_idx, : self.cfg.vocab]))
-                for _, slot_idx in batch
-            ]
-        else:
-            for req, slot_idx in batch:
-                prompt = jnp.asarray(req.prompt[None], jnp.int32)
-                logits, cache1 = self._prefill(self.params, prompt)
-                if self.paged:
-                    ids = self._slot_ids_row(req.req_id)
-                    self._cache = self._insert_pages(
-                        self._cache, cache1, jnp.asarray(ids)
-                    )
-                    if self.spec_k:
-                        _, dcache1 = self._draft_prefill(self.draft_params, prompt)
-                        self._draft_cache = self._insert_pages(
-                            self._draft_cache,
-                            dcache1,
-                            jnp.asarray(
-                                self._slot_ids_row(req.req_id, self.draft_pages)
-                            ),
-                        )
-                else:
-                    self._cache = self._admit_cache(
-                        self._cache, cache1, jnp.int32(slot_idx)
-                    )
-                firsts.append(
-                    int(np.argmax(np.asarray(logits[0, : self.cfg.vocab], np.float32)))
-                )
+        )
+        sp = max(len(req.prompt) for req, _ in batch)
+        with self._admit_span("serve.admit.dispatch", reqs=len(batch), padded_len=sp):
+            self._ensure_cache()
+            if self.paged:
+                self._apply_cow()  # allocate-time COW copies land before insert
+            if batched:
+                logits = self._prefill_batch(batch, sp)
+            else:
+                logits = [self._prefill_one(req, slot_idx) for req, slot_idx in batch]
+        with self._admit_span("serve.admit.pull"):
+            if batched:
+                logits_np = np.asarray(logits, np.float32)
+                firsts = [
+                    int(np.argmax(logits_np[slot_idx, : self.cfg.vocab]))
+                    for _, slot_idx in batch
+                ]
+            else:
+                firsts = [
+                    int(np.argmax(np.asarray(lg[0, : self.cfg.vocab], np.float32)))
+                    for lg in logits
+                ]
         now = time.perf_counter()
         for (req, slot_idx), first in zip(batch, firsts):
             slot = self.slots[slot_idx]
@@ -1049,7 +1085,8 @@ class ServeEngine:
                 response_producer.send_meta(
                     response_topic,
                     {"req_id": req_id, "kind": "delta",
-                     "token": token, "index": index},
+                     "token": token, "index": index,
+                     "sent_at": time.perf_counter()},
                 )
 
         def finish_if_done(slot_idx: int) -> bool:
@@ -1124,6 +1161,7 @@ class ServeEngine:
             while True:
                 batch: list[tuple[Request, int]] = []
                 taken: set[int] = set()
+                start = time.perf_counter()
                 while len(taken) < len(self.slots):
                     action, req, target, why = pop_next(taken)
                     if action == "reject":
@@ -1131,9 +1169,15 @@ class ServeEngine:
                         continue
                     if action != "admit":
                         break
+                    # a request that arrived while the batch formed waited 0
+                    wait = max(0.0, start - req.arrived)
+                    self.metrics["queue_wait_s"] += wait
                     # allocate now (so can_admit sees this batch's pages);
                     # prefill + insert run once for the whole batch below
-                    self._allocate_for(req)
+                    with self._admit_span("serve.admit.allocate",
+                                          prompt_len=len(req.prompt),
+                                          wait_us=1e6 * wait):
+                        self._allocate_for(req)
                     batch.append((req, target))
                     taken.add(target)
                     if not batching:
@@ -1141,10 +1185,12 @@ class ServeEngine:
                 if not batch:
                     return admitted
                 firsts = self._insert_prefill(batch)
-                for (req, target), first in zip(batch, firsts):
-                    send_delta(req.req_id, first, 0)
-                    finish_if_done(target)  # 1-token request: done at admission
-                    admitted += 1
+                with self._admit_span("serve.admit.emit", reqs=len(batch)):
+                    for (req, target), first in zip(batch, firsts):
+                        send_delta(req.req_id, first, 0)
+                        finish_if_done(target)  # 1-token request: done at admission
+                        admitted += 1
+                self.metrics["admissions"] += 1
 
         def serve_loop():
             while True:
@@ -1166,7 +1212,9 @@ class ServeEngine:
                             # arrival or close; the tick bounds shutdown,
                             # not wake-up
                             self.metrics["idle_waits"] += 1
-                            cond.wait(_WAIT_TICK)
+                            with _span("serve.idle",
+                                       step=self.metrics["decode_steps"]):
+                                cond.wait(_WAIT_TICK)
                     continue
                 if self.spec_k:
                     # speculative multi-token step: draft proposes, target
@@ -1176,54 +1224,61 @@ class ServeEngine:
                 # batched decode step: every slot's last generated token is
                 # fed back at that slot's own position (idle slots decode
                 # garbage against the null page — never read)
-                tokens = np.zeros((len(self.slots),), np.int32)
-                lens = np.zeros((len(self.slots),), np.int32)
-                for i in active:
-                    s = self.slots[i]
-                    tokens[i] = s.generated[-1]
-                    lens[i] = s.pos
-                self._ensure_cache()
-                if self.paged:
-                    # the page holding position pos must exist and be owned
-                    # before the step writes it: extend — and any
-                    # copy-on-write it triggers — happens pre-step
+                step = self.metrics["decode_steps"]
+                width = self._bt_width(max(
+                    self.pages.pages_needed(self.slots[i].pos + 1)
+                    for i in active
+                )) if self.paged else 0
+                with _span("serve.decode.prepare", step=step,
+                           active=len(active), width=width):
+                    tokens = np.zeros((len(self.slots),), np.int32)
+                    lens = np.zeros((len(self.slots),), np.int32)
                     for i in active:
                         s = self.slots[i]
-                        if self.pages.extend(s.req.req_id, s.pos + 1):
-                            s.pages = self.pages.pages_of(s.req.req_id)
-                    self._apply_cow()
-                    width = self._bt_width(max(
-                        self.pages.pages_needed(self.slots[i].pos + 1)
-                        for i in active
-                    ))
-                    bt = np.full(
-                        (len(self.slots), width), self._null_page, np.int32
-                    )
-                    for i in active:
+                        tokens[i] = s.generated[-1]
+                        lens[i] = s.pos
+                    self._ensure_cache()
+                    if self.paged:
+                        # the page holding position pos must exist and be
+                        # owned before the step writes it: extend — and any
+                        # copy-on-write it triggers — happens pre-step
+                        for i in active:
+                            s = self.slots[i]
+                            if self.pages.extend(s.req.req_id, s.pos + 1):
+                                s.pages = self.pages.pages_of(s.req.req_id)
+                        self._apply_cow()
+                        bt = np.full(
+                            (len(self.slots), width), self._null_page, np.int32
+                        )
+                        for i in active:
+                            s = self.slots[i]
+                            cov = self.pages.pages_needed(s.pos + 1)
+                            bt[i, :cov] = s.pages[:cov]
+                with _span("serve.decode.dispatch", step=step):
+                    if self.paged:
+                        self._cache, logits = self._decode(
+                            self.params, self._cache, jnp.asarray(bt),
+                            jnp.asarray(tokens[:, None]), jnp.asarray(lens),
+                        )
+                    else:
+                        self._cache, logits = self._decode(
+                            self.params, self._cache,
+                            jnp.asarray(tokens[:, None]), jnp.asarray(lens),
+                        )
+                with _span("serve.decode.pull", step=step):
+                    logits_np = np.asarray(logits, np.float32)
+                    nxts = np.argmax(logits_np[active, : self.cfg.vocab], axis=-1)
+                with _span("serve.decode.emit", step=step, tokens=len(active)):
+                    for i, nxt in zip(active, nxts.tolist()):
                         s = self.slots[i]
-                        cov = self.pages.pages_needed(s.pos + 1)
-                        bt[i, :cov] = s.pages[:cov]
-                    self._cache, logits = self._decode(
-                        self.params, self._cache, jnp.asarray(bt),
-                        jnp.asarray(tokens[:, None]), jnp.asarray(lens),
-                    )
-                else:
-                    self._cache, logits = self._decode(
-                        self.params, self._cache, jnp.asarray(tokens[:, None]),
-                        jnp.asarray(lens),
-                    )
+                        s.generated.append(nxt)
+                        s.pos += 1  # the fed-back token's KV is now cached
+                        if not self.paged:
+                            self.pages.extend(s.req.req_id, s.pos)
+                        self.metrics["tokens"] += 1
+                        send_delta(s.req.req_id, nxt, len(s.generated) - 1)
+                        finish_if_done(i)
                 self.metrics["decode_steps"] += 1
-                logits_np = np.asarray(logits, np.float32)
-                for i in active:
-                    s = self.slots[i]
-                    nxt = int(np.argmax(logits_np[i, : self.cfg.vocab]))
-                    s.generated.append(nxt)
-                    s.pos += 1  # the fed-back token's KV is now cached
-                    if not self.paged:
-                        self.pages.extend(s.req.req_id, s.pos)
-                    self.metrics["tokens"] += 1
-                    send_delta(s.req.req_id, nxt, len(s.generated) - 1)
-                    finish_if_done(i)
 
         try:
             serve_loop()

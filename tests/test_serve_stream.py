@@ -271,6 +271,27 @@ class TestMetaOnlyEvents:
         assert len(client.ignored_events) == 3
         assert client.results["x"].stream_tokens == [7]
 
+    def test_client_delta_without_sent_at_reaches_on_delta(self):
+        """A delta from an older engine (or built by hand) carries no
+        ``sent_at``: the client handles it as before, with no stream hop."""
+        ns = f"mo-{new_key()}"
+        producer = StreamProducer(QueuePublisher(ns), {"r": Store(f"{ns}-s")})
+        consumer = StreamConsumer(QueueSubscriber("r", ns), timeout=5)
+        got = []
+        client = ServeClient(consumer, on_delta=lambda *a: got.append(a))
+        producer.send_meta(
+            "r", {"req_id": "y", "kind": "delta", "token": 3, "index": 0}
+        )
+        producer.send_meta(
+            "r", {"req_id": "y", "kind": "delta", "token": 4, "index": 1,
+                  "sent_at": time.perf_counter()}
+        )
+        producer.close_topic("r")
+        client.collect()
+        assert got == [("y", 3, 0), ("y", 4, 1)]
+        assert client.results["y"].stream_tokens == [3, 4]
+        assert not client.ignored_events and not client.out_of_order
+
     def test_client_duplicate_rejection_spares_live_record(self):
         """An engine 'error' for a req_id that is already streaming is the
         duplicate being refused — the live record keeps collecting and
