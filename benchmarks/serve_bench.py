@@ -34,8 +34,9 @@ Gated metrics:
   only the pages a sequence occupies; the dense step attends over the
   whole max_len cache — the paging claim, measured.
 - ``batched_prefill_speedup``  — wall time admitting a slots-sized
-  backlog one prefill at a time over admitting it as ONE padded prefill
-  + one multi-page insert (same engine, both paths warm).
+  backlog one request per admission batch over admitting it as ONE
+  admission batch (both prefill one row per request; the batch shares
+  one pull and one emit; same engine, both paths warm).
 - ``prefix_pages_saved_ratio`` — fresh pages allocated WITHOUT prefix
   sharing over fresh pages WITH it, for a workload of prompts sharing a
   64-token system prefix.  Deterministic page arithmetic (refcounted
@@ -317,9 +318,10 @@ BP_ROUNDS = 3
 
 
 def bench_batched_prefill(engine, metrics: dict) -> None:
-    """Admission wall for a slots-sized backlog: one-at-a-time prefill vs
-    ONE padded prefill + one multi-page insert (max_new=1 keeps the
-    workload prefill-only; both modes hit warm compilations)."""
+    """Admission wall for a slots-sized backlog: one request per admission
+    batch vs ONE admission batch, each prefilling a row per request
+    (max_new=1 keeps the workload prefill-only; both modes hit warm
+    compilations)."""
     walls = {True: [], False: []}
     seq = [True, False] * BP_ROUNDS
     for r, mode in enumerate(seq):

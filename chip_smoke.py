@@ -247,8 +247,8 @@ def one_chip(args) -> int:
         for name, fn, fargs in (
             (f"decode step ({SLOTS} slots, width {width})", engine._decode,
              (params, engine._cache, bt, tok, at128)),
-            (f"prefill ({SLOTS} x 128 tokens)", engine._prefill_many,
-             (params, jnp.ones((SLOTS, 128), jnp.int32), at128)),
+            ("prefill (1 x 128 tokens)", engine._prefill,
+             (params, jnp.ones((1, 128), jnp.int32))),
         ):
             lowered = fn.lower(*fargs)
             has = "tpu_custom_call" in lowered.as_text()

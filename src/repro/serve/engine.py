@@ -29,9 +29,11 @@ table (``pages_of``, null-padded to a power-of-two width so recompiles
 stay bounded), decodes every slot at its own position, and scatters back
 *only the one page each slot wrote* — donated, so the pool updates in
 place and a short sequence touches its own pages, never ``max_len``.
-Admission inserts prefilled KV page-by-page (``batch_prefill=True`` admits
-up to ``slots`` queued requests in one padded prefill + one donated
-multi-page insert), and ``share_prefixes=True`` aliases common prompt
+Admission inserts prefilled KV page-by-page: each admitted request runs
+its own one-row prefill at its own length, then one donated insert of its
+pages (``batch_prefill=True`` drains up to ``slots`` queued requests into
+one admission batch: one dispatch, one pull of the first tokens, one
+emit), and ``share_prefixes=True`` aliases common prompt
 prefixes through the PageTable's refcounted cells — copy-on-write events
 are mirrored onto the pool as device page copies before the step that
 would diverge.  ``paged=False`` keeps the dense ``(L, B, S, ...)`` layout
@@ -205,7 +207,6 @@ class ServeEngine:
         self.spec_k = int(spec_k)
         self.draft_model = draft_model
         self.draft_params = draft_params if draft_params is not None else {}
-        self._can_batch = hasattr(self.model, "prefill_batch")
         # pool geometry, pinned at construction (tests may shrink the
         # allocator's num_pages afterwards to force backpressure — the
         # device pool keeps its build-time size, so every id stays valid)
@@ -258,24 +259,12 @@ class ServeEngine:
         self._prefill = jax.jit(
             lambda p, tokens: self.model.prefill(p, tokens, self.max_len)
         )
-        if self._can_batch:
-            self._prefill_many = jax.jit(
-                lambda p, tokens, lens: self.model.prefill_batch(
-                    p, tokens, lens, self.max_len
-                )
-            )
         if self.spec_k:
             self._spec_draft = jax.jit(self._spec_draft_body, donate_argnums=(1,))
             self._spec_verify = jax.jit(self._spec_verify_body, donate_argnums=(1,))
             self._draft_prefill = jax.jit(
                 lambda p, tokens: self.draft_model.prefill(p, tokens, self.max_len)
             )
-            if hasattr(self.draft_model, "prefill_batch"):
-                self._draft_prefill_many = jax.jit(
-                    lambda p, tokens, lens: self.draft_model.prefill_batch(
-                        p, tokens, lens, self.max_len
-                    )
-                )
         self._cache = None  # paged: (L, P+1, ps, ...); dense: (L, B, S, ...)
         self._draft_cache = None  # spec_k only: draft model's page pool
         self._live_prompts: dict[str, np.ndarray] = {}  # for prefix sharing
@@ -299,7 +288,10 @@ class ServeEngine:
             "queued_admissions": 0,
             "max_pending": 0,
             "malformed_events": 0,
-            "batched_prefills": 0,
+            "batched_prefills": 0,  # admission batches of more than one request
+            # tokens run through the target's prefill programs, padding
+            # rows and positions included
+            "prefill_tokens": 0,
             "prefix_shared_pages": 0,
             "cow_page_copies": 0,
             "spec_steps": 0,
@@ -405,12 +397,12 @@ class ServeEngine:
         )
 
     def _insert_body(self, pool, caches, page_ids):
-        """Paged admission insert: ``caches`` (L, Bk, max_len, ...) from
-        prefill, viewed as (L, Bk*pages_per_slot, page_size, ...) pages;
-        ``page_ids`` (Bk*pages_per_slot,) their physical destinations.
-        Pad rows, unowned tails, and *shared (borrowed) prefix pages* all
-        point at the null page — the insert never writes a page another
-        sequence owns."""
+        """Paged admission insert: ``caches`` (L, 1, max_len, ...) from one
+        request's prefill, viewed as (L, pages_per_slot, page_size, ...)
+        pages; ``page_ids`` (pages_per_slot,) their physical destinations.
+        The unowned tail and *shared (borrowed) prefix pages* point at the
+        null page — the insert never writes a page another sequence
+        owns."""
         ps = self.pages.page_size
 
         def one(pool_leaf, c):
@@ -650,56 +642,11 @@ class ServeEngine:
         return _span(name, step=self.metrics["decode_steps"],
                      batch=self.metrics["admissions"], **stats)
 
-    def _prefill_batch(self, batch: list[tuple[Request, int]], sp: int):
-        """Enqueue one prefill of every slot's row, padded to ``sp`` tokens,
-        and one donated multi-page insert; returns the prefill logits."""
-        B = len(self.slots)
-        mp = self._pages_per_slot
-        tokens = np.zeros((B, sp), np.int32)
-        lens = np.ones((B,), np.int32)  # pad rows decode garbage, unread
-        ids = np.full((B * mp,), self._null_page, np.int32)
-        for req, slot_idx in batch:
-            tokens[slot_idx, : len(req.prompt)] = req.prompt
-            lens[slot_idx] = len(req.prompt)
-            ids[slot_idx * mp : (slot_idx + 1) * mp] = self._slot_ids_row(
-                req.req_id
-            )
-        logits, caches = self._prefill_many(
-            self.params, jnp.asarray(tokens), jnp.asarray(lens)
-        )
-        self._cache = self._insert_pages(self._cache, caches, jnp.asarray(ids))
-        if self.spec_k:
-            ids_d = np.full((B * mp,), self._null_page, np.int32)
-            for req, slot_idx in batch:
-                ids_d[slot_idx * mp : (slot_idx + 1) * mp] = (
-                    self._slot_ids_row(req.req_id, self.draft_pages)
-                )
-            if hasattr(self.draft_model, "prefill_batch"):
-                _, dcaches = self._draft_prefill_many(
-                    self.draft_params, jnp.asarray(tokens), jnp.asarray(lens)
-                )
-                self._draft_cache = self._insert_pages(
-                    self._draft_cache, dcaches, jnp.asarray(ids_d)
-                )
-            else:
-                for req, slot_idx in batch:
-                    prompt = jnp.asarray(req.prompt[None], jnp.int32)
-                    _, dcache1 = self._draft_prefill(self.draft_params, prompt)
-                    self._draft_cache = self._insert_pages(
-                        self._draft_cache,
-                        dcache1,
-                        jnp.asarray(
-                            self._slot_ids_row(req.req_id, self.draft_pages)
-                        ),
-                    )
-        if len(batch) > 1:
-            self.metrics["batched_prefills"] += 1
-        return logits
-
     def _prefill_one(self, req: Request, slot_idx: int):
         """Enqueue one request's prefill and insert; returns its logits."""
         prompt = jnp.asarray(req.prompt[None], jnp.int32)
         logits, cache1 = self._prefill(self.params, prompt)
+        self.metrics["prefill_tokens"] += prompt.size
         if self.paged:
             ids = self._slot_ids_row(req.req_id)
             self._cache = self._insert_pages(
@@ -722,34 +669,33 @@ class ServeEngine:
 
     def _insert_prefill(self, batch: list[tuple[Request, int]]) -> list[int]:
         """Prefill + device insert for admitted requests; returns each
-        request's first token (from the prefill logits).  One padded
-        prefill and one donated multi-page insert cover the whole batch on
-        the paged path; the dense path and non-batching models insert one
-        request at a time."""
-        batched = self.paged and self._can_batch and (
-            self.batch_prefill or len(batch) > 1
-        )
-        sp = max(len(req.prompt) for req, _ in batch)
-        with self._admit_span("serve.admit.dispatch", reqs=len(batch), padded_len=sp):
+        request's first token (from the prefill logits).
+
+        Every request runs its own one-row prefill at its own length, then
+        its own insert; the batch shares one dispatch span, one pull of the
+        logits and one emit, not a program.  Rows of different requests
+        would share only the read of the weights: a prefill row of more
+        than about 240 tokens is compute-bound alone (the v5e's ridge
+        point, 197e12 FLOP/s over 819e9 B/s), so padding rows up to the
+        slot count, or to the batch's longest prompt, adds work nobody
+        reads.  And a one-row prefill reaches only the shapes a request
+        sent alone reaches, so warming each prompt length alone warms
+        every batch: a row count that followed the batch size would meet
+        its first multi-request batch uncompiled, while serving."""
+        tokens = sum(len(req.prompt) for req, _ in batch)
+        with self._admit_span("serve.admit.dispatch", reqs=len(batch),
+                              rows=len(batch), tokens=tokens):
             self._ensure_cache()
             if self.paged:
                 self._apply_cow()  # allocate-time COW copies land before insert
-            if batched:
-                logits = self._prefill_batch(batch, sp)
-            else:
-                logits = [self._prefill_one(req, slot_idx) for req, slot_idx in batch]
+            logits = [self._prefill_one(req, slot_idx) for req, slot_idx in batch]
         with self._admit_span("serve.admit.pull"):
-            if batched:
-                logits_np = np.asarray(logits, np.float32)
-                firsts = [
-                    int(np.argmax(logits_np[slot_idx, : self.cfg.vocab]))
-                    for _, slot_idx in batch
-                ]
-            else:
-                firsts = [
-                    int(np.argmax(np.asarray(lg[0, : self.cfg.vocab], np.float32)))
-                    for lg in logits
-                ]
+            firsts = [
+                int(np.argmax(np.asarray(lg[0, : self.cfg.vocab], np.float32)))
+                for lg in jax.device_get(logits)
+            ]
+        if len(batch) > 1:
+            self.metrics["batched_prefills"] += 1
         now = time.perf_counter()
         for (req, slot_idx), first in zip(batch, firsts):
             slot = self.slots[slot_idx]
@@ -1157,7 +1103,7 @@ class ServeEngine:
                 failed, state["failed"] = state["failed"], []
             for rid, why in failed:  # puller-detected per-request failures
                 send_reject(rid, why)
-            batching = self.paged and self.batch_prefill and self._can_batch
+            batching = self.paged and self.batch_prefill
             while True:
                 batch: list[tuple[Request, int]] = []
                 taken: set[int] = set()
@@ -1173,7 +1119,7 @@ class ServeEngine:
                     wait = max(0.0, start - req.arrived)
                     self.metrics["queue_wait_s"] += wait
                     # allocate now (so can_admit sees this batch's pages);
-                    # prefill + insert run once for the whole batch below
+                    # prefill + insert of each request are dispatched below
                     with self._admit_span("serve.admit.allocate",
                                           prompt_len=len(req.prompt),
                                           wait_us=1e6 * wait):

@@ -740,8 +740,9 @@ class TestPagedDecode:
     all bit-identical to the sequential reference."""
 
     def test_batched_admission_bit_identical(self):
-        """A backlog admitted into 4 free slots goes through ONE padded
-        prefill + one multi-page insert, and changes no tokens."""
+        """A backlog admitted into 4 free slots goes through one admission
+        batch (a one-row prefill and an insert per request), and changes
+        no tokens."""
         rng = np.random.default_rng(7)
         engine = make_engine(slots=4, max_len=32, page_size=4)
         reqs = {
@@ -769,6 +770,59 @@ class TestPagedDecode:
                 CFG, prompt, max_new, max_len=32
             ), rid
         assert engine.metrics["batched_prefills"] == 0
+        engine.close()
+
+    @pytest.mark.parametrize(
+        "slots,spec_k", [(4, 0), (8, 0), (4, 2)], ids=["4", "8", "4-spec"]
+    )
+    def test_admission_batch_prefills_one_row_per_request(self, slots, spec_k):
+        """A backlog of 2 admitted in one batch runs one one-row prefill at
+        the prompt's own length per request, target and draft alike: no
+        pad rows, no padded positions, and the same tokens."""
+        kw = {"spec_k": spec_k, "draft_model": CountingModel(CFG)} if spec_k else {}
+        # r0 reserves the whole 6-page pool, so r1 and r2 queue behind it
+        # and are admitted together once it finishes
+        engine = make_engine(slots=slots, max_len=32, page_size=4,
+                             num_pages=6, **kw)
+        rng = np.random.default_rng(11)
+        reqs = {
+            "r0": (rng.integers(1, CFG.vocab, 4).astype(np.int32), 20),
+            "r1": (rng.integers(1, CFG.vocab, 3).astype(np.int32), 5),
+            "r2": (rng.integers(1, CFG.vocab, 6).astype(np.int32), 6),
+        }
+        calls = {"_prefill": [], "_draft_prefill": [], "_insert_pages": []}
+
+        def spy(attr, record):
+            fn = getattr(engine, attr)
+
+            def wrapped(*args):
+                calls[attr].append(record(*args))
+                return fn(*args)
+
+            setattr(engine, attr, wrapped)
+
+        spy("_prefill", lambda p, tokens: tokens.shape)
+        # the rows of each inserted cache leaf
+        spy("_insert_pages",
+            lambda pool, caches, ids: {c.shape[1] for c in jax.tree.leaves(caches)})
+        if spec_k:
+            spy("_draft_prefill", lambda p, tokens: tokens.shape)
+        completed, _ = serve(engine, reqs)
+        for rid, (prompt, max_new) in reqs.items():
+            assert completed[rid]["tokens"] == reference_decode(
+                CFG, prompt, max_new, max_len=32
+            ), rid
+        want = sorted((1, len(prompt)) for prompt, _ in reqs.values())
+        assert sorted(calls["_prefill"]) == want
+        assert sorted(calls["_draft_prefill"]) == (want if spec_k else [])
+        assert calls["_insert_pages"] == [{1}] * (
+            2 * len(reqs) if spec_k else len(reqs)
+        )
+        assert engine.metrics["prefill_tokens"] == sum(
+            len(prompt) for prompt, _ in reqs.values()
+        )
+        assert engine.metrics["batched_prefills"] == 1
+        assert engine.metrics["admissions"] == 2
         engine.close()
 
     def test_prefix_sharing_aliases_full_pages(self):
