@@ -4,8 +4,10 @@
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell names a configuration (``configs/<config>.json``) and a traffic mix
-(``traffic/<mix>.json``); per-layer metrics are ``metrics/<metric>.py``.
-Everything is found by name; nothing here is particular to one cell.
+(``traffic/<mix>.json``); per-layer metrics are ``metrics/<metric>.py``; the
+architecture is ``archs/<model_type>.py``, by the configuration's published
+``model_type``.  Everything is found by name; nothing here is particular to
+one cell or one architecture.
 
 A run:
 1. checks the device: a TPU of a kind in ``peaks.json``, as many chips as
@@ -20,9 +22,9 @@ A run:
    JAX's profiler and the run reports the per-layer metrics instead;
 4. closes intake, waits for every request in flight to finish, reads peak
    device memory, frees the server, and checks the answers against the
-   plain float32 reference (``reference.py``) on a sample drawn from the
-   seed; each number compared is printed beside its limit, last on
-   standard error and last in the result line;
+   plain float32 reference (``reference.py`` over the architecture's
+   block) on a sample drawn from the seed; each number compared is printed
+   beside its limit, last on standard error and last in the result line;
 5. prints one JSON line as the last line of standard output.
 
 ``--rehearse`` runs the same steps off the chip with the program's smoke
@@ -33,6 +35,10 @@ mix; it never prints a result and exits 3 when every step ran.
 benchmark's runs: the float8 reference's first choices take the served
 tokens' place in ``max_logit_gap``, so a sound control prints
 ``correct: false``.
+
+A run that fails exits 1 and prints no result; its last line on standard
+error is ``[bench] FAIL`` and why, the exception's type first where it was
+not foreseen.
 """
 from __future__ import annotations
 
@@ -47,10 +53,12 @@ import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
 import random  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
+import traceback  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
@@ -68,6 +76,11 @@ DRAIN_TIMEOUT_S = 240.0
 REHEARSE_ENGINE = {"slots": 4, "max_len": 256}
 REHEARSE_CAPS = {"prompt_tokens": 128, "output_tokens": 24}
 REHEARSE_LEAD_IN_S = 2.0
+# what an architecture's module defines; it defines ``gaps`` or else
+# ``hidden`` and ``logits`` (see ``load_arch``)
+ARCH_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+ARCH_NAMES = ("check", "rehearse", "shapes", "controls", "dense_flops_per_token",
+              "head_flops", "flash_attention", "paged_attention")
 
 
 class Fail(Exception):
@@ -97,6 +110,7 @@ class Cell:
         self.chips = self.entry["chips"]
         conf = {c["name"]: c for c in bench["configs"]}[self.entry["config"]]
         self.config = json.loads((ROOT / conf["file"]).read_text())
+        self.model = load_arch(self.config.get("model_type"))
         self.mix = json.loads((HERE / "traffic" / f"{self.entry['traffic']}.json").read_text())
         self.run_seconds = bench["run_seconds"]
 
@@ -126,17 +140,9 @@ class Cell:
         if self.rehearse:
             return get_smoke_config(prog["arch"])
         cfg = get_config(prog["arch"]).with_(**prog["overrides"])
-        c = self.config
-        want = {
-            "d_model": c["hidden_size"], "d_ff": c["intermediate_size"],
-            "n_heads": c["num_attention_heads"], "n_kv_heads": c["num_key_value_heads"],
-            "n_layers": c["num_hidden_layers"], "vocab": c["vocab_size"],
-            "tie_embeddings": c["tie_word_embeddings"], "rope_theta": c["rope_theta"],
-            "padded_vocab": prog["padded_vocab"], "head_dim_": c["hidden_size"] // c["num_attention_heads"],
-        }
-        got = {k: getattr(cfg, k) for k in want}
-        if got != want or cfg.family != "dense" or cfg.norm != "rmsnorm" or cfg.rotary_pct != 1.0:
-            raise Fail(f"program config {prog['arch']} departs from the file: {got} vs {want}")
+        why = self.model.check(cfg, self.config)
+        if why:
+            raise Fail(f"program config {prog['arch']} departs from the file: {why}")
         return cfg
 
     def arch(self, cfg=None) -> dict:
@@ -144,16 +150,42 @@ class Cell:
         it: the file's keys as run (the smoke preset's when rehearsing)."""
         c = self.config
         a = {k: v for k, v in c.items() if not isinstance(v, (dict, list))}
-        a["embed_std"] = c["weights"]["embed_std"]
+        a.update(c["weights"])
         a["padded_vocab"] = c["program"]["padded_vocab"]
-        if self.rehearse:
-            a.update(
-                hidden_size=cfg.d_model, intermediate_size=cfg.d_ff,
-                num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.n_kv_heads,
-                num_hidden_layers=cfg.n_layers, vocab_size=cfg.vocab,
-                padded_vocab=cfg.padded_vocab, tie_word_embeddings=cfg.tie_embeddings,
-            )
-        return a
+        return self.model.rehearse(a, cfg) if self.rehearse else a
+
+
+def load_arch(model_type):
+    """The architecture's module, ``archs/<model_type>.py``.  It defines
+    ``ARCH_NAMES``:
+
+    - ``check(cfg, config)``: how the program's ``ArchConfig`` departs from
+      the configuration file, or None;
+    - ``rehearse(arch, cfg)``: the architecture dict at the sizes of the
+      program's smoke preset ``cfg``;
+    - ``shapes(arch)``: the table of weights ``weights.make`` draws;
+    - ``controls(arch, config)``: ``{name: arch}``, variants whose widest
+      gap ``--control`` logs beside the float8 one;
+    - ``dense_flops_per_token(arch)``, ``head_flops(arch)``,
+      ``flash_attention(arch, prompt_lens)``, ``paged_attention(arch,
+      ctx_lens)``: the counts ``work.Work`` sums;
+
+    and the reference: ``hidden(w, arch, tokens, fp8)`` and ``logits(w,
+    arch, h, fp8)``, which ``reference.gaps`` runs, or a ``gaps`` of its own."""
+    if not isinstance(model_type, str) or not ARCH_NAME.fullmatch(model_type):
+        raise Fail(f"the configuration's model_type {model_type!r} names no archs/ module")
+    path = HERE / "archs" / f"{model_type}.py"
+    if not path.exists():
+        raise Fail(f"no archs/{model_type}.py for model_type {model_type!r}")
+    module = "arch_" + re.sub(r"\W", "_", model_type)
+    spec = importlib.util.spec_from_file_location(module, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    need = list(ARCH_NAMES) + ([] if hasattr(mod, "gaps") else ["hidden", "logits"])
+    missing = [n for n in need if not callable(getattr(mod, n, None))]
+    if missing:
+        raise Fail(f"archs/{model_type}.py does not define {', '.join(missing)}")
+    return mod
 
 
 def load_reader(name: str):
@@ -225,7 +257,7 @@ class Window:
     """The traced window: device traces, counter deltas, and what the client
     saw, for ``metrics/<name>.py`` readers."""
 
-    def __init__(self, *, traces, counters, arch, peak, engine, prefilled, decoded_ctx):
+    def __init__(self, *, traces, counters, arch, work, peak, engine, prefilled, decoded_ctx):
         self.traces = traces
         self.counters = counters
         self.arch = arch
@@ -233,7 +265,7 @@ class Window:
         self.engine = engine
         self.prefilled = prefilled  # prompt lengths admitted in the window
         self.decoded_ctx = decoded_ctx  # context length of each decoded token
-        self.work = work
+        self.work = work  # work.Work of the architecture
 
     @property
     def traced(self) -> bool:
@@ -382,7 +414,7 @@ class Run:
         if self.args.rehearse:
             interpret_kernels()
         self.device_one_chip()
-        params = weights.make(self.arch, self.args.seed, self.dev)
+        params = weights.make(self.cell.model.shapes(self.arch), self.args.seed, self.dev)
         self._check_layout(params)
         jax.block_until_ready(params)
         self.server = EngineServer(cfg, self.cell.engine, params,
@@ -403,7 +435,8 @@ class Run:
                             is_leaf=lambda x: isinstance(x, ParamSpec))
         got = jax.tree.map(lambda a: (tuple(a.shape), str(a.dtype)), params)
         if want != got:
-            raise Fail(f"the program's parameter layout departs from weights.py: {want} vs {got}")
+            raise Fail(f"the program's parameter layout departs from archs/"
+                       f"{self.cell.config['model_type']}.py: {want} vs {got}")
 
     def warm(self):
         """Each warm-up request alone, through the served path."""
@@ -532,8 +565,9 @@ class Run:
         for rid, ts in self.rec.times.items():
             plen = len(self.reqs[rid].prompt)
             decoded += [plen + i for i, t_ in enumerate(ts) if i >= 1 and lo <= t_ < hi]
-        w = Window(traces=[t], counters=self.counters, arch=self.arch, peak=self.peak,
-                   engine=self.cell.engine, prefilled=prefilled, decoded_ctx=decoded)
+        w = Window(traces=[t], counters=self.counters, arch=self.arch,
+                   work=work.Work(self.cell.model), peak=self.peak, engine=self.cell.engine,
+                   prefilled=prefilled, decoded_ctx=decoded)
         log(t.describe())
         out = {}
         for m in self.cell.per_layer:
@@ -573,26 +607,26 @@ class Run:
     def compare(self, sample: list[str]) -> dict:
         """The widest gap of the served tokens over the sampled requests;
         with ``--control``, also of the float8 reference's first choices and
-        of the served tokens against the reference at the published RMSNorm
-        eps."""
+        of the served tokens against each of the architecture's control
+        variants."""
         import reference
         import weights
 
-        arch = self.arch
-        w = weights.make(arch, self.args.seed)
+        arch, model = self.arch, self.cell.model
+        w = weights.make(model.shapes(arch), self.args.seed)
         pad_to = self.cell.engine["max_len"]
         out_pad = max(traffic.support(self.cell.mix["output_tokens"]))
         control = self.args.control
-        published = dict(arch, rms_norm_eps=self.cell.config["reduced"]["rms_norm_eps"]["published"])
-        worst = {"served": 0.0, "control": 0.0, "published_eps": 0.0}
+        variants = model.controls(arch, self.cell.config) if control else {}
+        worst = {"served": 0.0, "control": 0.0, **{k: 0.0 for k in variants}}
         n = 0
         for r in sample:
             prompt, served = self.reqs[r].prompt, self.rec.result[r]
-            g = reference.gaps(w, arch, prompt, served, pad_to=pad_to, out_pad=out_pad,
+            g = reference.gaps(model, w, arch, prompt, served, pad_to=pad_to, out_pad=out_pad,
                                control=control)
-            if control:
-                g["published_eps"] = reference.gaps(w, published, prompt, served, pad_to=pad_to,
-                                                    out_pad=out_pad)["served"]
+            for k, a in variants.items():
+                g[k] = reference.gaps(model, w, a, prompt, served, pad_to=pad_to,
+                                      out_pad=out_pad)["served"]
             for k, v in g.items():
                 worst[k] = max(worst[k], float(v.max()))
             n += len(served)
@@ -649,8 +683,9 @@ class Run:
         limit = self.cell.config["check"]["rehearse_max_logit_gap" if self.args.rehearse
                                           else "max_logit_gap"]
         if self.args.control:  # the float8 picks in the served tokens' place
-            log(f"control: served tokens' widest gap {gap['served']!r}; at the published "
-                f"RMSNorm eps {gap['published_eps']!r}")
+            variants = [f"{k} {v!r}" for k, v in gap.items() if k not in ("served", "control", "tokens")]
+            log(f"control: served tokens' widest gap {gap['served']!r}; variants: "
+                + (", ".join(variants) or "none"))
             checks["max_logit_gap"] = {"value": gap["control"], "limit": limit}
         else:
             checks["max_logit_gap"] = {"value": gap["served"], "limit": limit}
@@ -711,6 +746,10 @@ def main(argv=None) -> int:
             shutil.rmtree(run.tmp, ignore_errors=True)
     except Fail as e:
         log(f"FAIL {e}")
+        return 1
+    except Exception as e:  # noqa: BLE001 - a run that fails says why, last
+        traceback.print_exc()
+        log(f"FAIL {type(e).__name__}: {e}")
         return 1
     if args.rehearse:
         log(f"rehearsal: every step ran (correct={out['correct']}); no result is printed off the chip")

@@ -28,7 +28,7 @@ sys.path.insert(0, str(HERE))
 
 import stats  # noqa: E402
 import traffic  # noqa: E402
-import work  # noqa: E402
+from work import Work  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -153,6 +153,9 @@ ARCH = {"hidden_size": 8, "num_attention_heads": 2, "num_key_value_heads": 1,
 
 
 def test_work_counts_by_hand():
+    import run
+
+    work = Work(run.load_arch("llama"))
     # head dim 4; per token per layer: q 8x8, k and v 8x4 each, o 8x8, mlp 3 x 8x16
     per_layer = 2 * (8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16)
     assert work.dense_flops_per_token(ARCH) == 3 * per_layer
@@ -225,7 +228,8 @@ def test_trace_reduction_on_a_cpu_trace(cpu_trace):
 def _window(traces, **kw):
     import run
 
-    base = dict(counters=None, arch={**ARCH, "padded_vocab": 100}, peak={
+    base = dict(counters=None, arch={**ARCH, "padded_vocab": 100},
+                work=Work(run.load_arch("llama")), peak={
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}, engine={"slots": 4},
         prefilled=[], decoded_ctx=[])
     base.update(kw)
@@ -285,6 +289,14 @@ def test_benchmark_json_names_and_files():
         assert (HERE / "traffic" / f"{w['traffic']}.json").exists()
         mine = [m for m in BENCH["per_layer"] if w["name"] in m.get("workloads", CELLS)]
         assert mine, w["name"]
+
+
+def test_every_per_layer_metric_names_the_cells_it_reads_in():
+    # a metric with no list would be owed by every cell added later
+    for m in BENCH["per_layer"]:
+        cells = m.get("workloads")
+        assert isinstance(cells, list) and cells, m["name"]
+        assert set(cells) <= set(CELLS), m["name"]
 
 
 # -- whole runs off the chip -------------------------------------------------------------

@@ -22,8 +22,10 @@ ARCH = {"hidden_size": 8, "num_attention_heads": 2, "num_key_value_heads": 1,
 
 def _window(traces, **kw):
     import run
+    from work import Work
 
-    base = dict(counters=None, arch={**ARCH, "padded_vocab": 100}, peak={
+    base = dict(counters=None, arch={**ARCH, "padded_vocab": 100},
+                work=Work(run.load_arch("llama")), peak={
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}, engine={"slots": 4},
         prefilled=[], decoded_ctx=[])
     base.update(kw)

@@ -1,14 +1,17 @@
-"""Seeded random weights of a Llama-style decoder, made on the device in one
-jitted call, in the layout and type they are served in.
+"""Seeded random weights, made on the device in one jitted call, in the
+layout and type they are served in.
 
-The benchmark makes the weights it hands the engine, and the reference makes
-them again from the same seed with this same code, so the reference takes
-nothing the program made.  Each leaf is drawn from the root key folded with
-a CRC-32 of its tree path, so a leaf's values depend only on the seed, its
-path and its shape.
+The table of leaves comes from the architecture's module
+(``archs/<model_type>.py``: ``shapes(arch)``), ``path -> (shape, dtype,
+scale, fan_in)``.  The benchmark makes the weights it hands the engine, and
+the reference makes them again from the same seed with this same code, so
+the reference takes nothing the program made.  Each leaf is drawn from the
+root key folded with a CRC-32 of its tree path, so a leaf's values depend
+only on the seed, its path and its shape.
 
-Weight matrices are normal with std 1/sqrt(fan-in of one layer), the
-embedding (and an untied head) normal with std ``embed_std``, norm scales 1.
+Weight matrices are normal with std 1/sqrt(fan-in of one layer) where the
+table gives no scale, otherwise with the stated std; float32 leaves of scale
+1 (norm scales) are ones.
 """
 from __future__ import annotations
 
@@ -19,33 +22,6 @@ import jax
 import jax.numpy as jnp
 
 BF16, F32 = jnp.bfloat16, jnp.float32
-
-
-def shapes(arch: dict) -> dict[tuple, tuple]:
-    """``path -> (shape, dtype, scale, fan_in)``: scale is the stated std
-    (or the fill of a norm scale), None means fan-in scaled; fan_in is what
-    one layer's matrix contracts over."""
-    E, F = arch["hidden_size"], arch["intermediate_size"]
-    H, Hkv = arch["num_attention_heads"], arch["num_key_value_heads"]
-    L, V = arch["num_hidden_layers"], arch["padded_vocab"]
-    D = E // H
-    std = arch["embed_std"]
-    t = {
-        ("embed", "embedding"): ((V, E), BF16, std, None),
-        ("final_norm", "scale"): ((E,), F32, 1.0, None),
-        ("dense_layers", "ln1", "scale"): ((L, E), F32, 1.0, None),
-        ("dense_layers", "ln2", "scale"): ((L, E), F32, 1.0, None),
-        ("dense_layers", "attn", "wq"): ((L, E, H, D), BF16, None, E),
-        ("dense_layers", "attn", "wk"): ((L, E, Hkv, D), BF16, None, E),
-        ("dense_layers", "attn", "wv"): ((L, E, Hkv, D), BF16, None, E),
-        ("dense_layers", "attn", "wo"): ((L, H, D, E), BF16, None, H * D),
-        ("dense_layers", "ffn", "wi"): ((L, E, F), BF16, None, E),
-        ("dense_layers", "ffn", "wg"): ((L, E, F), BF16, None, E),
-        ("dense_layers", "ffn", "wo"): ((L, F, E), BF16, None, F),
-    }
-    if not arch["tie_word_embeddings"]:
-        t[("embed", "unembed")] = ((E, V), BF16, std, None)
-    return t
 
 
 def root_key(seed: int):
@@ -67,9 +43,9 @@ def _leaf(key, shape, dtype, scale, fan_in):
     return (jax.random.normal(key, shape, F32) * std).astype(dtype)
 
 
-def make(arch: dict, seed: int, device=None) -> dict:
-    """The nested parameter dict, made in one jitted call on ``device``."""
-    table = shapes(arch)
+def make(table: dict, seed: int, device=None) -> dict:
+    """The nested parameter dict of ``table``, made in one jitted call on
+    ``device``."""
 
     def build(key):
         out: dict = {}

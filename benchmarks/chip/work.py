@@ -5,62 +5,39 @@ past a sequence's end) is not needed work.
 
 A matrix product of (m, k) by (k, n) is 2mkn operations.  Bytes are those a
 kernel must read and write once in the served type (2 bytes, bfloat16).
+
+The per-architecture counts live in ``archs/<model_type>.py``:
+``dense_flops_per_token(arch)`` and ``head_flops(arch)`` for one token,
+``flash_attention(arch, prompt_lens)`` and ``paged_attention(arch,
+ctx_lens)`` as (ops, bytes).  ``Work`` sums them into whole steps.
 """
 from __future__ import annotations
 
 DTYPE_BYTES = 2
 
 
-def _dims(arch: dict):
-    E, H = arch["hidden_size"], arch["num_attention_heads"]
-    return (E, H, arch["num_key_value_heads"], E // H,
-            arch["intermediate_size"], arch["num_hidden_layers"], arch["vocab_size"])
+class Work:
+    """An architecture's counts and the sums built on them, under the names
+    the per-layer readers call (``Window.work``)."""
 
+    def __init__(self, model):
+        self.dense_flops_per_token = model.dense_flops_per_token
+        self.head_flops = model.head_flops
+        self.flash_attention = model.flash_attention
+        self.paged_attention = model.paged_attention
+        self.least_time = least_time
 
-def dense_flops_per_token(arch: dict) -> float:
-    """Projections and MLP of every layer, for one token."""
-    E, H, Hkv, D, F, L, _ = _dims(arch)
-    return 2.0 * L * (E * H * D + 2 * E * Hkv * D + H * D * E + 3 * E * F)
+    def prefill_flops(self, arch: dict, prompt_lens) -> float:
+        """A prefill of these prompts: every token through every layer, and
+        the head at each prompt's last token (the only logits it needs)."""
+        attn, _ = self.flash_attention(arch, prompt_lens)
+        return (sum(prompt_lens) * self.dense_flops_per_token(arch) + attn
+                + len(prompt_lens) * self.head_flops(arch))
 
-
-def head_flops(arch: dict) -> float:
-    """The output head for one token, over the real vocabulary."""
-    E, *_, V = _dims(arch)
-    return 2.0 * E * V
-
-
-def flash_attention(arch: dict, prompt_lens) -> tuple[float, float]:
-    """Causal self-attention over each prompt, every layer: (ops, bytes)."""
-    E, H, Hkv, D, F, L, V = _dims(arch)
-    flops = bytes_ = 0.0
-    for s in prompt_lens:
-        flops += L * 4.0 * H * D * s * (s + 1) / 2  # QK^T and PV, lower triangle
-        bytes_ += L * s * (2 * H + 2 * Hkv) * D * DTYPE_BYTES  # q, k, v in; o out
-    return flops, bytes_
-
-
-def paged_attention(arch: dict, ctx_lens) -> tuple[float, float]:
-    """One decode query per sequence over its ``ctx`` live cached tokens,
-    every layer: (ops, bytes)."""
-    E, H, Hkv, D, F, L, V = _dims(arch)
-    flops = bytes_ = 0.0
-    for c in ctx_lens:
-        flops += L * 4.0 * H * D * c
-        bytes_ += L * (2 * c * Hkv * D + 2 * H * D) * DTYPE_BYTES  # K, V; q, o
-    return flops, bytes_
-
-
-def prefill_flops(arch: dict, prompt_lens) -> float:
-    """A prefill of these prompts: every token through every layer, and the
-    head at each prompt's last token (the only logits it needs)."""
-    attn, _ = flash_attention(arch, prompt_lens)
-    return sum(prompt_lens) * dense_flops_per_token(arch) + attn + len(prompt_lens) * head_flops(arch)
-
-
-def decode_flops(arch: dict, ctx_lens) -> float:
-    """Decode tokens, each at its context length (keys it attends)."""
-    attn, _ = paged_attention(arch, ctx_lens)
-    return len(ctx_lens) * (dense_flops_per_token(arch) + head_flops(arch)) + attn
+    def decode_flops(self, arch: dict, ctx_lens) -> float:
+        """Decode tokens, each at its context length (keys it attends)."""
+        attn, _ = self.paged_attention(arch, ctx_lens)
+        return len(ctx_lens) * (self.dense_flops_per_token(arch) + self.head_flops(arch)) + attn
 
 
 def least_time(flops: float, bytes_: float, peak: dict) -> tuple[float, str]:
