@@ -8,6 +8,7 @@ def _import_all():
     from repro.configs import (  # noqa: F401
         deepseek_7b,
         deepseek_v3_671b,
+        granite_4_0_h_micro,
         granite_8b,
         granite_moe_1b_a400m,
         paper_synthetic,
@@ -30,6 +31,7 @@ def _import_all():
         stablelm_1_6b,
         deepseek_7b,
         zamba2_1_2b,
+        granite_4_0_h_micro,
         paper_synthetic,
     ]
     return {m.CONFIG.name: m for m in mods}

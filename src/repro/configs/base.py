@@ -11,7 +11,7 @@ def pad_to(x: int, m: int) -> int:
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str  # dense | moe | mla_moe | encdec | rwkv | hybrid
+    family: str  # dense | moe | mla_moe | encdec | rwkv | hybrid | mamba_attn
     n_layers: int
     d_model: int
     n_heads: int
@@ -63,6 +63,19 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_conv: int = 4
     shared_attn_every: int = 0  # zamba2: shared block cadence
+    ssm_conv_bias: bool = False  # bias on the causal conv (granite-4.0-h)
+
+    # Mamba2 layers with attention at a fixed period (granite-4.0-h): layer
+    # i is attention when i % attn_layer_period == attn_layer_offset
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    # Granite's scalings: the embedding, each residual branch and the logits
+    # (divided by logits_scaling); attention_multiplier is the softmax scale,
+    # 0 meaning 1/sqrt(head dim)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     # training
     dtype: str = "bfloat16"

@@ -21,13 +21,14 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@partial(jax.jit, static_argnames=("causal", "impl", "block_q", "block_k"))
+@partial(jax.jit, static_argnames=("causal", "scale", "impl", "block_q", "block_k"))
 def flash_attention(
     q: jax.Array,  # (B, Sq, H, D)
     k: jax.Array,  # (B, Sk, Hkv, D)
     v: jax.Array,  # (B, Sk, Hkv, D)
     *,
     causal: bool = True,
+    scale: float | None = None,  # None: 1/sqrt(D)
     impl: str = "auto",  # auto | pallas | interpret | jnp
     block_q: int = 128,
     block_k: int = 128,
@@ -41,20 +42,20 @@ def flash_attention(
     if impl == "jnp":
         from repro.models.layers import blockwise_attention
 
-        return blockwise_attention(q, k, v, causal=causal, q_offset=Sk - Sq)
+        return blockwise_attention(q, k, v, causal=causal, q_offset=Sk - Sq, scale=scale)
     qbh = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
     kbh = k.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, D)
     vbh = v.transpose(0, 2, 1, 3).reshape(B * Hkv, Sk, D)
     obh = flash_attention_bh(
         qbh, kbh, vbh,
-        group=g, causal=causal, q_offset=Sk - Sq,
+        group=g, causal=causal, scale=scale, q_offset=Sk - Sq,
         block_q=block_q, block_k=block_k,
         interpret=(impl == "interpret"),
     )
     return obh.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
 
-@partial(jax.jit, static_argnames=("impl",))
+@partial(jax.jit, static_argnames=("scale", "impl"))
 def paged_attention(
     q: jax.Array,  # (B, T, H, D) — T freshly written tokens per sequence
     k_pages: jax.Array,  # (P, page_size, Hkv, D) — the KV page pool
@@ -62,6 +63,7 @@ def paged_attention(
     block_tables: jax.Array,  # (B, n) int32 physical page ids, token order
     lens: jax.Array,  # (B,) int32 valid tokens through each FIRST query
     *,
+    scale: float | None = None,  # None: 1/sqrt(D)
     impl: str = "auto",  # auto | pallas | interpret | jnp
 ) -> jax.Array:
     """Model-layout paged-attention decode over a block-table-indexed pool.
@@ -77,7 +79,7 @@ def paged_attention(
     if impl == "jnp":
         from repro.models.layers import paged_decode_attention
 
-        return paged_decode_attention(q, k_pages, v_pages, block_tables, lens)
+        return paged_decode_attention(q, k_pages, v_pages, block_tables, lens, scale=scale)
     # queries-major row stacking: row t*g + lane matches the kernel's
     # ``t = row // group`` per-row causal mask
     qg = (
@@ -91,6 +93,7 @@ def paged_attention(
         qg, k_pages.transpose(0, 2, 1, 3), v_pages.transpose(0, 2, 1, 3),
         bt, lens.astype(jnp.int32),
         num_queries=T,
+        scale=scale,
         interpret=(impl == "interpret"),
     )
     return (
@@ -137,10 +140,12 @@ def ssd(
     Bm: jax.Array,  # (B, S, N)
     Cm: jax.Array,  # (B, S, N)
     D: jax.Array,  # (H,)
+    state: jax.Array | None = None,  # (B, H, P, N) initial state; None: zeros
     *,
     impl: str = "auto",
     chunk: int = 128,
-) -> jax.Array:
+) -> tuple[jax.Array, jax.Array]:
+    """Model-layout SSD: (y (B, S, H, P), final state (B, H, P, N) f32)."""
     B, S, H, P = x.shape
     N = Bm.shape[-1]
     if impl == "auto":
@@ -148,16 +153,22 @@ def ssd(
     if impl == "jnp":
         from repro.models.ssm import ssd_chunked
 
-        out, _ = ssd_chunked(x, dt, la, Bm, Cm, D, chunk=chunk)
-        return out
+        return ssd_chunked(x, dt, la, Bm, Cm, D, state, chunk=chunk)
+    C = min(chunk, S)
+    f32 = jnp.float32
     xbh = x.transpose(0, 2, 1, 3).reshape(B * H, S, P)
-    dtbh = dt.transpose(0, 2, 1).reshape(B * H, S)
-    labh = la.transpose(0, 2, 1).reshape(B * H, S)
-    bbh = jnp.broadcast_to(Bm[:, None], (B, H, S, N)).reshape(B * H, S, N)
-    cbh = jnp.broadcast_to(Cm[:, None], (B, H, S, N)).reshape(B * H, S, N)
-    dbh = jnp.broadcast_to(D[None], (B, H)).reshape(B * H, 1)
-    obh = ssd_bh(
-        xbh, dtbh, labh, bbh, cbh, dbh,
-        chunk=chunk, interpret=(impl == "interpret"),
+    dtbh = dt.astype(f32).transpose(0, 2, 1).reshape(B * H, S)
+    # cumulative log decay within each chunk, the kernel's la_t
+    lac = jnp.cumsum(
+        la.astype(f32).transpose(0, 2, 1).reshape(B * H, S // C, C), axis=-1
+    ).reshape(B * H, S)
+    dbh = jnp.broadcast_to(D.astype(f32)[None], (B, H)).reshape(B * H, 1, 1)
+    s0 = jnp.zeros((B, H, P, N), f32) if state is None else state.astype(f32)
+    s0 = s0.transpose(0, 1, 3, 2).reshape(B * H, N, P)
+    obh, sf = ssd_bh(
+        xbh, dtbh[..., None], lac[..., None], lac[:, None, :],
+        Cm, Bm.transpose(0, 2, 1), dbh, s0,
+        heads=H, chunk=C, interpret=(impl == "interpret"),
     )
-    return obh.reshape(B, H, S, P).transpose(0, 2, 1, 3)
+    y = obh.reshape(B, H, S, P).transpose(0, 2, 1, 3)
+    return y, sf.reshape(B, H, N, P).transpose(0, 1, 3, 2)

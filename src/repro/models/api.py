@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.models.encdec import EncDecLM
+from repro.models.hybrid import HybridLM
 from repro.models.layers import ModelContext
 from repro.models.rwkv import RWKV6LM
 from repro.models.ssm import Zamba2LM
@@ -29,6 +30,8 @@ def build_model(ctx: ModelContext):
         return RWKV6LM(ctx)
     if fam == "hybrid":
         return Zamba2LM(ctx)
+    if fam == "mamba_attn":
+        return HybridLM(ctx)
     raise ValueError(f"unknown family {fam!r}")
 
 
@@ -92,6 +95,8 @@ def decode_cache_specs(model, cfg: ArchConfig, batch: int, max_len: int):
         return model.state_specs(batch)
     if cfg.family == "hybrid":
         return model.state_specs(batch, max_len)
+    if cfg.family == "mamba_attn":
+        return {"kv": model.cache_specs(batch, max_len), "state": model.slot_state_specs(batch)}
     return model.cache_specs(batch, max_len)
 
 

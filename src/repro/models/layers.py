@@ -328,16 +328,18 @@ def paged_decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def _decode_attention_core(ctx: "ModelContext", q, k_cache, v_cache, length):
+def _decode_attention_core(ctx: "ModelContext", q, k_cache, v_cache, length,
+                           scale: float | None = None):
     """Decode-step dispatch: when kernels are enabled, view the dense
     per-slot cache as contiguous ``ctx.page_size`` pages (an arange block
     table) and run the paged-attention kernel; else the plain masked jnp
     decode attention.  A shape the kernel cannot take raises rather than
     quietly running jnp.  Handles T ≥ 1 query tokens (q ``(B, T, H, D)``):
-    both backends mask query ``t`` to keys ``< length + t``."""
+    both backends mask query ``t`` to keys ``< length + t``.  ``scale``
+    None is 1/sqrt(head dim)."""
     B, S, Hkv, Dv = v_cache.shape
     if not ctx.use_kernels:
-        return decode_attention(q, k_cache, v_cache, length)
+        return decode_attention(q, k_cache, v_cache, length, scale=scale)
     ps = ctx.page_size
     if q.shape[-1] != Dv or S % ps:
         raise ValueError(
@@ -351,30 +353,30 @@ def _decode_attention_core(ctx: "ModelContext", q, k_cache, v_cache, length):
     vp = v_cache.reshape(B * n, ps, Hkv, Dv)
     bt = jnp.arange(B * n, dtype=jnp.int32).reshape(B, n)
     lens = jnp.full((B,), length, jnp.int32)
-    return paged_attention(q, kp, vp, bt, lens)
+    return paged_attention(q, kp, vp, bt, lens, scale=scale)
 
 
 def _attention_core(ctx: "ModelContext", q, k, v, *, causal: bool,
                     scale: float | None = None):
     """Dispatch: Pallas flash-attention kernel (TPU / interpret) when
     ``ctx.use_kernels``, else the pure-jnp blockwise path.  The kernel
-    takes a uniform head dim, the default scale and scanned layers; any
-    other shape raises rather than quietly running jnp."""
+    takes a uniform head dim and scanned layers; any other shape raises
+    rather than quietly running jnp.  ``scale`` None is 1/sqrt(head dim)."""
     cfg = ctx.cfg
     if ctx.use_kernels:
         Sq, Sk = q.shape[1], k.shape[1]
-        if (scale is not None or q.shape[-1] != v.shape[-1]
+        if (q.shape[-1] != v.shape[-1]
                 or not cfg.scan_layers
                 or (Sq > 128 and Sq % 128) or (Sk > 128 and Sk % 128)):
             raise ValueError(
                 f"flash-attention kernel cannot take q {q.shape} / v "
-                f"{v.shape} (scale={scale}, scan_layers={cfg.scan_layers}): "
-                "it needs a uniform head dim, the default scale, scanned "
-                "layers and sequences of at most 128 or a multiple of 128"
+                f"{v.shape} (scan_layers={cfg.scan_layers}): it needs a "
+                "uniform head dim, scanned layers and sequences of at most "
+                "128 or a multiple of 128"
             )
         from repro.kernels.ops import flash_attention
 
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, scale=scale)
     return blockwise_attention(q, k, v, causal=causal, scale=scale,
                                unroll=not cfg.scan_layers,
                                causal_skip=cfg.attn_causal_skip)
@@ -405,6 +407,7 @@ def apply_attention(
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     rotary_dim = int(cfg.rotary_pct * Dh) if cfg.rotary_pct else 0
     rotary_dim -= rotary_dim % 2
+    scale = cfg.attention_multiplier or None  # None: 1/sqrt(Dh)
 
     src = x if kv is None else kv
     q = jnp.einsum("bse,ehd->bshd", x, params["wq"])
@@ -427,12 +430,13 @@ def apply_attention(
             k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, cache_index, 1)
             v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, cache_index, 1)
             new_cache = {"k": k_cache, "v": v_cache}
-            o = _decode_attention_core(ctx, q, k_cache, v_cache, cache_index + 1)
+            o = _decode_attention_core(ctx, q, k_cache, v_cache, cache_index + 1,
+                                       scale=scale)
         else:  # prefill: fill cache, run blockwise
             new_cache = {"k": k, "v": v}
-            o = _attention_core(ctx, q, k, v, causal=causal)
+            o = _attention_core(ctx, q, k, v, causal=causal, scale=scale)
     else:
-        o = _attention_core(ctx, q, k, v, causal=causal)
+        o = _attention_core(ctx, q, k, v, causal=causal, scale=scale)
 
     out = jnp.einsum("bshd,hde->bse", o, params["wo"])
     return ctx.constrain(out, ("batch", "seq", None)), new_cache

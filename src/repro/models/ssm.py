@@ -107,12 +107,12 @@ def ssd_step(x, dt, a_log, Bm, Cm, D, state):
 # ---------------------------------------------------------------------------
 
 
-def mamba2_specs(cfg: ArchConfig) -> dict:
+def mamba2_mixer_specs(cfg: ArchConfig) -> dict:
+    """The Mamba2 mixer alone: projections, causal conv, SSD, gated norm."""
     E = cfg.d_model
     d_inner, H, P, N = _dims(cfg)
     conv_dim = d_inner + 2 * N
-    return {
-        "ln": L.norm_specs(cfg, E),
+    s = {
         "in_proj_z": ParamSpec((E, d_inner), ("embed", "mlp")),
         "in_proj_x": ParamSpec((E, d_inner), ("embed", "mlp")),
         "in_proj_B": ParamSpec((E, N), ("embed", None)),
@@ -125,10 +125,34 @@ def mamba2_specs(cfg: ArchConfig) -> dict:
         "norm_gate": ParamSpec((d_inner,), (None,), jnp.float32, 1.0),
         "out_proj": ParamSpec((d_inner, E), ("mlp", "embed")),
     }
+    if cfg.ssm_conv_bias:
+        s["conv_b"] = ParamSpec((conv_dim,), ("mlp",), jnp.float32, 0.0)
+    return s
 
 
-def _causal_conv(u, w, conv_state=None):
-    """Depthwise causal conv along S.  u (B,S,Dc); w (K,Dc);
+def mamba2_specs(cfg: ArchConfig) -> dict:
+    return {"ln": L.norm_specs(cfg, cfg.d_model), **mamba2_mixer_specs(cfg)}
+
+
+def mamba2_state_specs(cfg: ArchConfig, batch_size: int, n_layers: int) -> dict:
+    """Decode state of ``n_layers`` stacked Mamba2 mixers: the conv's last
+    K-1 inputs and the SSD state."""
+    d_inner, H, P, N = _dims(cfg)
+    conv_dim = d_inner + 2 * N
+    per = {
+        "conv": ParamSpec(
+            (batch_size, cfg.ssm_conv - 1, conv_dim), ("batch", None, "mlp"),
+            jnp.dtype(cfg.dtype), 0.0,
+        ),
+        "ssd": ParamSpec(
+            (batch_size, H, P, N), ("batch", "heads", None, None), jnp.float32, 0.0
+        ),
+    }
+    return stack_specs(per, n_layers)
+
+
+def _causal_conv(u, w, conv_state=None, b=None):
+    """Depthwise causal conv along S.  u (B,S,Dc); w (K,Dc); b (Dc,) or None;
     conv_state (B,K-1,Dc) carries the last K-1 inputs for decode/chunking."""
     K = w.shape[0]
     if conv_state is None:
@@ -137,16 +161,37 @@ def _causal_conv(u, w, conv_state=None):
     out = sum(
         up[:, i : i + u.shape[1]] * w[i].astype(u.dtype) for i in range(K)
     )
+    if b is not None:
+        out = out + b.astype(u.dtype)
     new_state = up[:, -(K - 1) :] if K > 1 else conv_state
     return jax.nn.silu(out), new_state
 
 
-def apply_mamba2(ctx, p, x, state, *, decode: bool):
-    """state: {"conv": (B,K-1,Dc), "ssd": (B,H,P,N)}."""
+def _ssd_core(ctx, x, dt, la, Bm, Cm, D, state):
+    """Prefill/train SSD dispatch: the Pallas kernel (``ops.ssd``) when
+    ``ctx.use_kernels``, else the jnp chunked scan.  A sequence the kernel
+    cannot tile raises rather than quietly running jnp."""
+    if ctx.use_kernels:
+        S = x.shape[1]
+        if S > 128 and S % 128:
+            raise ValueError(
+                f"ssd kernel cannot take a sequence of {S} tokens: it needs at "
+                "most 128 or a multiple of 128"
+            )
+        from repro.kernels.ops import ssd
+
+        return ssd(x, dt, la, Bm, Cm, D, state)
+    return ssd_chunked(x, dt, la, Bm, Cm, D, state, unroll=not ctx.cfg.scan_layers)
+
+
+def mamba2_mixer(ctx, p, h, state, *, decode: bool):
+    """The Mamba2 mixer on normed input ``h`` (B,S,E); returns (out, state).
+    state: {"conv": (B,K-1,Dc), "ssd": (B,H,P,N)}; without ``decode`` it may
+    be None or lack either entry (a zero start)."""
     cfg = ctx.cfg
     d_inner, H, P, N = _dims(cfg)
-    B_, S, E = x.shape
-    h = L.apply_norm(cfg, p["ln"], x)
+    B_, S, E = h.shape
+    state = state or {}
     z = jnp.einsum("bse,ei->bsi", h, p["in_proj_z"])
     xs = jnp.einsum("bse,ei->bsi", h, p["in_proj_x"])
     Bm = jnp.einsum("bse,en->bsn", h, p["in_proj_B"])
@@ -156,7 +201,7 @@ def apply_mamba2(ctx, p, x, state, *, decode: bool):
 
     u = jnp.concatenate([xs, Bm, Cm], axis=-1)
     conv_in_state = state["conv"] if decode else None
-    u, new_conv = _causal_conv(u, p["conv_w"], conv_in_state)
+    u, new_conv = _causal_conv(u, p["conv_w"], conv_in_state, p.get("conv_b"))
     xs, Bm, Cm = u[..., :d_inner], u[..., d_inner : d_inner + N], u[..., d_inner + N :]
     xh = xs.reshape(B_, S, H, P)
 
@@ -169,14 +214,21 @@ def apply_mamba2(ctx, p, x, state, *, decode: bool):
         )
         y = y[:, None]
     else:
-        y, new_ssd = ssd_chunked(xh, dt, la, Bm, Cm, p["D"], state.get("ssd"),
-                                 unroll=not ctx.cfg.scan_layers)
+        y, new_ssd = _ssd_core(ctx, xh, dt, la, Bm, Cm, p["D"], state.get("ssd"))
 
     y = y.reshape(B_, S, d_inner)
     y = L.rmsnorm_nogain(y * jax.nn.silu(z)) * p["norm_gate"].astype(y.dtype)
     out = jnp.einsum("bsi,ie->bse", y, p["out_proj"])
     out = ctx.constrain(out, ("batch", None, None))
-    return x + out, {"conv": new_conv, "ssd": new_ssd}
+    return out, {"conv": new_conv, "ssd": new_ssd}
+
+
+def apply_mamba2(ctx, p, x, state, *, decode: bool):
+    """Pre-norm Mamba2 block with its residual.  state: {"conv": (B,K-1,Dc),
+    "ssd": (B,H,P,N)}."""
+    h = L.apply_norm(ctx.cfg, p["ln"], x)
+    out, new_state = mamba2_mixer(ctx, p, h, state, decode=decode)
+    return x + out, new_state
 
 
 # ---------------------------------------------------------------------------
@@ -210,19 +262,7 @@ class Zamba2LM:
 
     # -- states/caches -----------------------------------------------------
     def mamba_state_specs(self, batch_size: int) -> dict:
-        cfg = self.cfg
-        d_inner, H, P, N = _dims(cfg)
-        conv_dim = d_inner + 2 * N
-        dt = jnp.dtype(cfg.dtype)
-        per = {
-            "conv": ParamSpec(
-                (batch_size, cfg.ssm_conv - 1, conv_dim), ("batch", None, "mlp"), dt, 0.0
-            ),
-            "ssd": ParamSpec(
-                (batch_size, H, P, N), ("batch", "heads", None, None), jnp.float32, 0.0
-            ),
-        }
-        return stack_specs(per, cfg.n_layers)
+        return mamba2_state_specs(self.cfg, batch_size, self.cfg.n_layers)
 
     def attn_cache_specs(self, batch_size: int, max_len: int) -> dict:
         cfg = self.cfg

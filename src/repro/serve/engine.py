@@ -70,9 +70,20 @@ keep every extend inside the admission reservation, so speculation can
 never OOM and pricing is unchanged.  The draft runs a second PageTable (its
 own Store, no prefix sharing) in lockstep: ``can_admit`` checks both pools
 and ``_finish`` frees both.
+
+Recurrent state (a model with ``slot_state_specs``, e.g. Mamba2 layers
+beside attention): besides the page pool of its attention layers' K/V the
+engine keeps a **state pool**, one row per slot, ``(L_state, slots, ...)``,
+built once.  Admission writes the prefill's final state into the admitted
+slot's row (``serve.admit.state``, donated, in place); every decode step
+reads and writes every slot's row in place beside the paged K/V (an idle
+slot's row is garbage, overwritten at its next admission before any read).
+A shared page prefix carries no state, so such a model shares no prefixes,
+and speculative decode, whose rollback would need state snapshots, raises.
 """
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import deque
@@ -180,7 +191,22 @@ class ServeEngine:
         # paged decode needs pages to tile max_len exactly; else dense
         self.paged = paged and max_len % page_size == 0
         self.batch_prefill = batch_prefill
-        self.share_prefixes = share_prefixes
+        # recurrent state, one row per slot, beside the K/V pages
+        slot_state = getattr(self.model, "slot_state_specs", None)
+        self._state_specs = slot_state(slots) if slot_state is not None else None
+        if self._state_specs is not None:
+            if spec_k > 0:
+                raise ValueError(
+                    "speculative decode cannot serve a model with recurrent "
+                    "state: rolling back rejected drafts needs state snapshots"
+                )
+            if not self.paged:
+                raise ValueError(
+                    "a model with recurrent state needs the paged cache layout "
+                    "(max_len a multiple of page_size, paged=True)"
+                )
+        # a shared page prefix carries no recurrent state: no sharing then
+        self.share_prefixes = share_prefixes and self._state_specs is None
         # Fleet hooks (serve/router.py).  ``on_load_change(pages_available)``
         # fires after every admission batch and every completion so an
         # engine can publish its capacity as store metadata (the router's
@@ -246,7 +272,16 @@ class ServeEngine:
         # the KV buffers in place instead of allocating a full copy per
         # token (self._cache is reassigned from the result, so the donated
         # input is never reused)
-        if self.paged:
+        if self._state_specs is not None:
+            self._state_shardings = sharding_tree(self._state_specs, ctx.rules, ctx.mesh)
+            self._state_row_bytes = sum(
+                math.prod(s.shape) // slots * jnp.dtype(s.dtype).itemsize
+                for s in jax.tree.leaves(
+                    self._state_specs, is_leaf=lambda x: isinstance(x, ParamSpec)
+                )
+            )
+            self._decode = jax.jit(self._decode_state_body, donate_argnums=(1, 2))
+        elif self.paged:
             self._decode = jax.jit(self._decode_paged_body, donate_argnums=(1,))
         else:
             self._decode = jax.jit(self._decode_body, donate_argnums=(1,))
@@ -266,6 +301,7 @@ class ServeEngine:
                 lambda p, tokens: self.draft_model.prefill(p, tokens, self.max_len)
             )
         self._cache = None  # paged: (L, P+1, ps, ...); dense: (L, B, S, ...)
+        self._state = None  # slot state models only: (L_state, slots, ...)
         self._draft_cache = None  # spec_k only: draft model's page pool
         self._live_prompts: dict[str, np.ndarray] = {}  # for prefix sharing
         # Per-request lifetimes, split by custodian.  Request-side payloads
@@ -303,6 +339,10 @@ class ServeEngine:
             # sum over admitted requests of their batch's start minus
             # Request.arrived (the puller's receipt)
             "queue_wait_s": 0.0,
+            # slot state models: prefill states written into slot rows, and
+            # state rows each decode step read and wrote, summed over steps
+            "state_inserts": 0,
+            "state_row_steps": 0,
         }
 
     def _page_bytes(self, page_size: int) -> int:
@@ -355,14 +395,7 @@ class ServeEngine:
         page coverage of the longest active slot, so the gathered view —
         and the attention the model runs inside it — scales with what the
         batch actually occupies, not with max_len."""
-        ps = self.pages.page_size
-        B, n = bt.shape
-
-        def gather(leaf):
-            g = leaf[:, bt]  # (L, B, n, ps, ...)
-            return g.reshape(g.shape[:2] + (n * ps,) + g.shape[4:])
-
-        dense = jax.tree.map(gather, pool)
+        dense = self._gather_dense(pool, bt)
 
         def one(cache_b, tok_b, len_b):
             c = jax.tree.map(lambda x: x[:, None], cache_b)
@@ -372,7 +405,23 @@ class ServeEngine:
         new_dense, logits = jax.vmap(
             one, in_axes=(1, 0, 0), out_axes=(1, 0)
         )(dense, tokens, lens)
+        return self._scatter_written(pool, new_dense, bt, lens), logits
 
+    def _decode_state_body(self, params, pool, state, bt, tokens, lens):
+        """Paged decode of a model with slot state: the K/V as in
+        ``_decode_paged_body``; the state pool ``(L_state, slots, ...)``
+        goes to the model whole, every row at its own position, and comes
+        back updated (donated: in place)."""
+        dense = self._gather_dense(pool, bt)
+        logits, new = self.model.decode_step(
+            params, {"kv": dense, "state": state}, tokens, lens
+        )
+        return self._scatter_written(pool, new["kv"], bt, lens), new["state"], logits
+
+    def _scatter_written(self, pool, new_dense, bt, lens):
+        """Scatter back the one page each row wrote (position ``lens[b]``)
+        from its contiguous view to its physical page."""
+        ps = self.pages.page_size
         page_slot = lens // ps  # (B,) block-table index of the written page
         dst = jnp.take_along_axis(bt, page_slot[:, None], axis=1)[:, 0]  # (B,)
 
@@ -383,11 +432,12 @@ class ServeEngine:
             written = jax.vmap(pick, in_axes=(1, 0), out_axes=1)(nd, page_slot)
             return leaf.at[:, dst].set(written.astype(leaf.dtype))
 
-        return jax.tree.map(scatter, pool, new_dense), logits
+        return jax.tree.map(scatter, pool, new_dense)
 
     def _admit_body(self, cache, one, slot_idx):
-        """Dense path: insert a (batch=1) prefill cache at slot
-        ``slot_idx`` — a dynamic per-slot update on donated buffers."""
+        """Insert a (batch=1) prefill cache (the dense path) or final state
+        (a slot state model) at slot ``slot_idx`` — a dynamic per-slot
+        update on donated buffers."""
         return jax.tree.map(
             lambda full, o: jax.lax.dynamic_update_index_in_dim(
                 full, o[:, 0].astype(full.dtype), slot_idx, 1
@@ -536,6 +586,9 @@ class ServeEngine:
         if self._cache is None:
             cache = materialize_params(self._cache_specs, jax.random.PRNGKey(0))
             self._cache = jax.device_put(cache, self._cache_shardings)
+        if self._state_specs is not None and self._state is None:
+            state = materialize_params(self._state_specs, jax.random.PRNGKey(0))
+            self._state = jax.device_put(state, self._state_shardings)
         if self.spec_k and self._draft_cache is None:
             cache = materialize_params(
                 self._draft_cache_specs, jax.random.PRNGKey(0)
@@ -643,10 +696,15 @@ class ServeEngine:
                      batch=self.metrics["admissions"], **stats)
 
     def _prefill_one(self, req: Request, slot_idx: int):
-        """Enqueue one request's prefill and insert; returns its logits."""
+        """Enqueue one request's prefill and its page insert; returns its
+        logits and, for a model with slot state, its final state (inserted
+        by the caller)."""
         prompt = jnp.asarray(req.prompt[None], jnp.int32)
         logits, cache1 = self._prefill(self.params, prompt)
         self.metrics["prefill_tokens"] += prompt.size
+        state1 = None
+        if self._state_specs is not None:
+            cache1, state1 = cache1["kv"], cache1["state"]
         if self.paged:
             ids = self._slot_ids_row(req.req_id)
             self._cache = self._insert_pages(
@@ -665,7 +723,7 @@ class ServeEngine:
             self._cache = self._admit_cache(
                 self._cache, cache1, jnp.int32(slot_idx)
             )
-        return logits
+        return logits, state1
 
     def _insert_prefill(self, batch: list[tuple[Request, int]]) -> list[int]:
         """Prefill + device insert for admitted requests; returns each
@@ -688,7 +746,17 @@ class ServeEngine:
             self._ensure_cache()
             if self.paged:
                 self._apply_cow()  # allocate-time COW copies land before insert
-            logits = [self._prefill_one(req, slot_idx) for req, slot_idx in batch]
+            outs = [self._prefill_one(req, slot_idx) for req, slot_idx in batch]
+        logits = [lg for lg, _ in outs]
+        for (_, slot_idx), (_, state1) in zip(batch, outs):
+            if state1 is None:
+                continue
+            # the slot's row is overwritten whole: nothing of its last
+            # sequence is read again
+            with self._admit_span("serve.admit.state", slot=slot_idx,
+                                  bytes=self._state_row_bytes):
+                self._state = self._admit_cache(self._state, state1, jnp.int32(slot_idx))
+            self.metrics["state_inserts"] += 1
         with self._admit_span("serve.admit.pull"):
             firsts = [
                 int(np.argmax(np.asarray(lg[0, : self.cfg.vocab], np.float32)))
@@ -1201,7 +1269,12 @@ class ServeEngine:
                             cov = self.pages.pages_needed(s.pos + 1)
                             bt[i, :cov] = s.pages[:cov]
                 with _span("serve.decode.dispatch", step=step):
-                    if self.paged:
+                    if self._state_specs is not None:
+                        self._cache, self._state, logits = self._decode(
+                            self.params, self._cache, self._state, jnp.asarray(bt),
+                            jnp.asarray(tokens[:, None]), jnp.asarray(lens),
+                        )
+                    elif self.paged:
                         self._cache, logits = self._decode(
                             self.params, self._cache, jnp.asarray(bt),
                             jnp.asarray(tokens[:, None]), jnp.asarray(lens),
@@ -1224,6 +1297,8 @@ class ServeEngine:
                         self.metrics["tokens"] += 1
                         send_delta(s.req.req_id, nxt, len(s.generated) - 1)
                         finish_if_done(i)
+                if self._state_specs is not None:  # beside decode_steps: one snapshot
+                    self.metrics["state_row_steps"] += len(self.slots)
                 self.metrics["decode_steps"] += 1
 
         try:

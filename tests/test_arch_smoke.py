@@ -118,6 +118,7 @@ def test_full_config_param_specs(name):
         "stablelm-1.6b": (1.3e9, 2.0e9),
         "deepseek-7b": (6e9, 8e9),
         "zamba2-1.2b": (0.9e9, 1.8e9),
+        "granite-4.0-h-micro": (3.0e9, 3.4e9),
     }
     lo, hi = expected[name]
     assert lo < n < hi, f"{name}: {n/1e9:.2f}B params outside [{lo/1e9},{hi/1e9}]B"

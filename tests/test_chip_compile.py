@@ -96,3 +96,46 @@ def test_flash_attention_prefill_compiles_for_v5e(one_chip, no_persistent_cache)
         sds((B, S, Hkv, D)),
     )
     assert "tpu_custom_call" in hlo
+
+
+def test_ssd_prefill_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The chunked SSD scan at granite-4.0-h-micro widths (64 heads of 64,
+    d_state 128, one group) for a 1024-token prompt, with its final state."""
+    from repro.configs import get_config
+
+    cfg = get_config("granite-4.0-h-micro")
+    B, S = 1, 1024
+    H, P, N = 2 * cfg.d_model // cfg.ssm_head_dim, cfg.ssm_head_dim, cfg.ssm_state
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = _hlo(
+        lambda x, dt, la, b, c, d: ops.ssd(x, dt, la, b, c, d, impl="pallas"),
+        sds((B, S, H, P), jnp.bfloat16), sds((B, S, H), jnp.float32),
+        sds((B, S, H), jnp.float32), sds((B, S, N), jnp.bfloat16),
+        sds((B, S, N), jnp.bfloat16), sds((H,), jnp.float32),
+    )
+    assert "tpu_custom_call" in hlo and "ssd_scan" in hlo
+
+
+def test_attention_kernels_compile_with_a_scale_for_v5e(one_chip, no_persistent_cache):
+    """Both attention kernels at granite-4.0-h-micro's softmax scale 1/64
+    (32 heads over 8 KV heads of 64)."""
+    H, Hkv, D, S, B, n = 32, 8, 64, 512, 4, 32
+    dt = jnp.bfloat16
+
+    def sds(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    flash = _hlo(
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=True, scale=1 / 64, impl="pallas"),
+        sds((1, S, H, D)), sds((1, S, Hkv, D)), sds((1, S, Hkv, D)),
+    )
+    paged = _hlo(
+        lambda q, kp, vp, bt, lens: ops.paged_attention(q, kp, vp, bt, lens, scale=1 / 64,
+                                                        impl="pallas"),
+        sds((B, 1, H, D)), sds((B * n + 1, PAGE, Hkv, D)), sds((B * n + 1, PAGE, Hkv, D)),
+        sds((B, n), jnp.int32), sds((B,), jnp.int32),
+    )
+    assert "tpu_custom_call" in flash and "tpu_custom_call" in paged

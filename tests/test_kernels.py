@@ -135,6 +135,42 @@ def test_paged_attention_jnp_fallback_matches_ref(B, P, n, ps, H, Hkv, D, dtype)
 
 
 @pytest.mark.parametrize("impl", ["interpret", "jnp"])
+def test_flash_attention_takes_a_scale(impl):
+    """A non-default softmax scale (granite-4.0-h's 1/D, not 1/sqrt(D))
+    matches the blockwise jnp attention at that scale; no scale is
+    bit-identical to the scale 1/sqrt(D) spelled out."""
+    from repro.models.layers import blockwise_attention
+
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = rand(k1, (1, 128, 4, 64), jnp.float32)
+    k = rand(k2, (1, 128, 2, 64), jnp.float32)
+    v = rand(k3, (1, 128, 2, 64), jnp.float32)
+    got = ops.flash_attention(q, k, v, causal=True, scale=1 / 64, impl=impl,
+                              block_q=64, block_k=64)
+    want = blockwise_attention(q, k, v, causal=True, scale=1 / 64)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    plain = ops.flash_attention(q, k, v, causal=True, impl=impl, block_q=64, block_k=64)
+    spelled = ops.flash_attention(q, k, v, causal=True, scale=1 / np.sqrt(64), impl=impl,
+                                  block_q=64, block_k=64)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(spelled))
+    assert not np.allclose(np.asarray(plain), np.asarray(got))
+
+
+@pytest.mark.parametrize("impl", ["interpret", "jnp"])
+def test_paged_attention_takes_a_scale(impl):
+    """The paged decode kernel at a non-default scale matches the dense
+    gather-and-softmax at that scale; no scale is bit-identical to
+    1/sqrt(D) spelled out."""
+    q, kp, vp, bt, lens = paged_case(13, 3, 24, 4, 8, 4, 2, 64, jnp.float32)
+    got = ops.paged_attention(q, kp, vp, bt, lens, scale=1 / 64, impl=impl)
+    want = paged_ref(q * (np.sqrt(64) / 64), kp, vp, np.asarray(bt), np.asarray(lens))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    plain = ops.paged_attention(q, kp, vp, bt, lens, impl=impl)
+    spelled = ops.paged_attention(q, kp, vp, bt, lens, scale=1 / np.sqrt(64), impl=impl)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(spelled))
+
+
+@pytest.mark.parametrize("impl", ["interpret", "jnp"])
 def test_paged_attention_ignores_padding_pages(impl):
     """Block-table entries beyond a row's length are never read: garbage
     (even out-of-range) padding ids change nothing — the property the
@@ -322,15 +358,39 @@ def test_ssd_kernel_vs_ref(B, S, H, P, N, chunk, dtype):
     Bm = rand(ks[3], (B, S, N), jnp.float32)
     Cm = rand(ks[4], (B, S, N), jnp.float32)
     D = rand(ks[5], (H,), jnp.float32)
-    ref, _ = ssd_ref(x, dt, la, Bm, Cm, D)
-    out = ops.ssd(x, dt, la, Bm, Cm, D, impl="interpret", chunk=chunk)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol(dtype)
-    )
-    out2 = ops.ssd(x, dt, la, Bm, Cm, D, impl="jnp", chunk=chunk)
-    np.testing.assert_allclose(
-        np.asarray(out2, np.float32), np.asarray(ref, np.float32), **tol(dtype)
-    )
+    ref, ref_state = ssd_ref(x, dt, la, Bm, Cm, D)
+    for impl in ("interpret", "jnp"):
+        out, state = ops.ssd(x, dt, la, Bm, Cm, D, impl=impl, chunk=chunk)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol(dtype)
+        )
+        np.testing.assert_allclose(np.asarray(state), np.asarray(ref_state), **tol(dtype))
+
+
+@pytest.mark.parametrize("B,S,H,P,N,chunk", [(1, 32, 2, 8, 16, 8), (2, 24, 3, 16, 8, 8)])
+def test_ssd_kernel_final_state_matches_chunked_and_steps(B, S, H, P, N, chunk):
+    """The kernel's final state (from a nonzero start) is ``ssd_chunked``'s
+    and the one-token recurrence's after S steps: what decode continues
+    from."""
+    from repro.models.ssm import ssd_chunked, ssd_step
+
+    ks = jax.random.split(jax.random.PRNGKey(6), 7)
+    x = rand(ks[0], (B, S, H, P), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    la = -jnp.abs(jax.random.normal(ks[2], (B, S, H)) * 0.3)
+    Bm = rand(ks[3], (B, S, N), jnp.float32)
+    Cm = rand(ks[4], (B, S, N), jnp.float32)
+    D = rand(ks[5], (H,), jnp.float32)
+    s0 = rand(ks[6], (B, H, P, N), jnp.float32)
+    y, state = ops.ssd(x, dt, la, Bm, Cm, D, s0, impl="interpret", chunk=chunk)
+    y_c, state_c = ssd_chunked(x, dt, la, Bm, Cm, D, s0, chunk=chunk)
+    s = s0
+    for t in range(S):
+        _, s = ssd_step(x[:, t], dt[:, t], la[:, t], Bm[:, t], Cm[:, t], D, s)
+    assert state.shape == (B, H, P, N) and state.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_c), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(state_c), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(s), rtol=1e-4, atol=1e-4)
 
 
 def test_ssd_decode_step_consistency():
