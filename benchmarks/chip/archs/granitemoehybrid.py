@@ -424,9 +424,11 @@ def weight_bytes(arch: dict) -> int:
                for shape, dtype, _, _ in shapes(arch).values())
 
 
-def decode_hbm_bytes(arch: dict, steps: int, ctx_lens) -> float:
-    """The least bytes ``steps`` decode steps move: the weights once a step;
-    for each decoded token, its live K/V in the attention layers and its
-    row of state read and written once."""
-    _, kv = paged_attention(arch, ctx_lens)
-    return steps * weight_bytes(arch) + kv + len(ctx_lens) * 2 * state_row_bytes(arch)
+def decode_hbm_bytes(arch: dict, steps: int, tokens: int, ctx: int) -> float:
+    """The least bytes of ``steps`` decode steps that decoded ``tokens``
+    tokens over ``ctx`` keys in all: the weights once a step; for each
+    decoded token, its live K/V in the attention layers (as
+    ``paged_attention``) and its row of state read and written once."""
+    d = _dims(arch)
+    kv = d["periods"] * (2 * ctx * d["Hkv"] * d["D"] + 2 * tokens * d["H"] * d["D"]) * DTYPE_BYTES
+    return steps * weight_bytes(arch) + kv + tokens * 2 * state_row_bytes(arch)

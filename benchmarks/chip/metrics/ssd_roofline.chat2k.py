@@ -1,25 +1,15 @@
 """Least time of the chunked SSD scan (``ssd_scan`` of
-``archs/granitemoehybrid.py``, loaded by path) for the prompts admitted
-inside the traced window, over the device time of the Pallas SSD kernel
-(the Mosaic custom call named ``ssd_scan``) inside the prefill programs
-(the engine's ``jit__lambda``).  The prompts are those of the window's own
+``archs/granitemoehybrid.py``) for the prompts admitted inside the traced
+window, over the device time of the Pallas SSD kernel (the Mosaic custom
+call named ``ssd_scan``) inside the prefill programs (the engine's
+``jit__lambda``).  The prompts are those of the window's own
 ``serve.admit.allocate`` spans (stat ``prompt_len``), so programs and
 prompts come from the same 10 s."""
-import importlib.util
-from pathlib import Path
-
+import run
 import spans
 
 PROGRAMS = ("jit__lambda",)
 EVENTS = ("ssd_scan",)
-ARCH = Path(__file__).resolve().parents[1] / "archs" / "granitemoehybrid.py"
-
-
-def _arch():
-    spec = importlib.util.spec_from_file_location("arch_granitemoehybrid_ssd", ARCH)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def read(w):
@@ -31,6 +21,7 @@ def read(w):
     if not secs or not prompts:
         return None
     spans.log(f"ssd kernel: {len(ops)} calls, {secs:.6f} s; {len(prompts)} prompts admitted")
-    least, bound = w.work.least_time(*_arch().ssd_scan(w.arch, prompts), w.peak)
+    scan = run.load_arch("granitemoehybrid").ssd_scan(w.arch, prompts)
+    least, bound = w.work.least_time(*scan, w.peak)
     spans.log(f"ssd scan least time {1e3 * least:.3f} ms, {bound}-bound")
     return 100.0 * least / secs

@@ -125,8 +125,39 @@ def test_counts_by_hand(arch_mod):
     # conv inputs (3 x 20, bf16) and SSD state (4 x 4 x 2, f32) of 3 layers
     row = 3 * (3 * 20 * 2 + 4 * 4 * 2 * 4)
     assert arch_mod.state_row_bytes(TINY) == row
-    kv = (2 * 5 * 1 * 4 + 2 * 2 * 4) * 2
-    assert arch_mod.decode_hbm_bytes(TINY, 2, [5]) == 2 * arch_mod.weight_bytes(TINY) + kv + 2 * row
+    # two steps decoding three tokens over 5 + 6 + 7 keys: K/V as the paged kernel reads it
+    _, kv = work.paged_attention(TINY, [5, 6, 7])
+    assert kv == (2 * 18 * 1 * 4 + 3 * 2 * 2 * 4) * 2
+    assert arch_mod.decode_hbm_bytes(TINY, 2, 3, 18) == 2 * arch_mod.weight_bytes(TINY) + kv + 3 * 2 * row
+
+
+MS = 1_000_000
+
+
+def test_decode_hbm_reader_counts_only_the_window(arch_mod):
+    """Programs and emit spans outside the 10-s window count on neither side."""
+    import run
+    import trace
+    from work import Work
+
+    t = trace.Trace.__new__(trace.Trace)
+    t.lo, t.hi = 10 * MS, 30 * MS
+    progs = [(5, 9), (10, 14), (20, 24), (30, 34)]  # the first and last lie outside
+    t.devices = [trace.DeviceTrace([], [trace.Event("_decode_state_body", a * MS, b * MS,
+                                                    "jit__decode_state_body", {})
+                                        for a, b in progs])]
+    t.host = [trace.Event("serve.decode.emit", (b + 1) * MS, (b + 2) * MS, "serve.decode.emit",
+                          {"step": i, "tokens": 2, "ctx": 100 + i})
+              for i, (_, b) in enumerate(progs)]  # emits at 10, 15, 25, 35 ms
+    peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    w = run.Window(traces=[t], counters={"decode_steps": 40}, arch=TINY, work=Work(arch_mod),
+                   peak=peak, engine={}, prefilled=[], decoded_ctx=[1000] * 80)
+    got = run.load_reader("decode_hbm_roofline.chat2k").read(w)
+    least = arch_mod.decode_hbm_bytes(TINY, 2, 6, 100 + 101 + 102)  # programs 1, 2; emits 0-2
+    assert got == pytest.approx(100 * least / (8e-3 * 819e9))
+    for e in t.host:  # a program without the stat: nothing to read
+        e.stats.pop("ctx")
+    assert run.load_reader("decode_hbm_roofline.chat2k").read(w) is None
 
 
 def test_state_rows_reader():

@@ -49,8 +49,10 @@ The engine thread marks each phase of ``run`` with a leaf
 ``serve.idle``): no span encloses another, so a profiler trace names every
 idle gap of the device by the phase the host was in.  A span carries its
 parent as a stat instead: ``step``, the ``decode_steps`` counter when it
-opens, and for admission ``batch``, the ``admissions`` counter.  With no
-profiler running a span costs about a microsecond.  Each token delta
+opens, and for admission ``batch``, the ``admissions`` counter.
+``serve.decode.emit`` also carries the step's decoded ``tokens`` and
+``ctx``, the keys their rows attended.  With no profiler running a span
+costs about a microsecond.  Each token delta
 carries ``sent_at``, the engine's ``time.perf_counter()`` at the send: a
 host-monotonic clock, comparable across processes on one host only.
 
@@ -1251,6 +1253,8 @@ class ServeEngine:
                         s = self.slots[i]
                         tokens[i] = s.generated[-1]
                         lens[i] = s.pos
+                    # keys the step's live rows attend: cached ones + the fed token
+                    ctx = int(lens[active].sum()) + len(active)
                     self._ensure_cache()
                     if self.paged:
                         # the page holding position pos must exist and be
@@ -1287,7 +1291,8 @@ class ServeEngine:
                 with _span("serve.decode.pull", step=step):
                     logits_np = np.asarray(logits, np.float32)
                     nxts = np.argmax(logits_np[active, : self.cfg.vocab], axis=-1)
-                with _span("serve.decode.emit", step=step, tokens=len(active)):
+                with _span("serve.decode.emit", step=step, tokens=len(active),
+                           ctx=ctx):
                     for i, nxt in zip(active, nxts.tolist()):
                         s = self.slots[i]
                         s.generated.append(nxt)

@@ -117,6 +117,17 @@ def test_admission_spans_carry_step_and_batch_decode_spans_step(served):
     assert all(sorted(v) == sorted(DECODE) for v in steps.values())
 
 
+def test_decode_emit_spans_count_tokens_and_the_keys_they_attend(served):
+    # one slot: each decode step emits one token; the j-th answer token
+    # (j >= 1; the prefill emits token 0) attends the prompt and j keys more
+    _, engine, lines = served
+    emit = [st for *_, st in _spans(lines, ("serve.decode.emit",))]
+    assert len(emit) == engine.metrics["decode_steps"]
+    want = [len(p) + j for p, n in REQS.values() for j in range(1, n)]
+    assert sum(st["tokens"] for st in emit) == len(want)
+    assert sum(st["ctx"] for st in emit) == sum(want)
+
+
 def test_engine_spans_are_leaves_on_one_thread(served):
     _, _, lines = served
     threads = {name for name, events in lines if any(e[0] in ENGINE for e in events)}
